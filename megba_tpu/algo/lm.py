@@ -143,6 +143,10 @@ class LMResult:
     recoveries: Optional[jax.Array] = None
 
 
+# The program's outermost device layer: every op of the LM solve sits
+# under it, so what no inner scope claims (the first linearisation's
+# cost sums, the loop test, the result) still has a layer name.
+@jax.named_scope("megba.lm_solve")
 def lm_solve(
     residual_jac_fn: Callable,
     cameras: jax.Array,
@@ -403,6 +407,13 @@ def lm_solve(
                 cluster_plan=cluster_plan, cam_fixed=cam_fixed,
                 smooth_omega=solver_opt.smooth_omega,
                 tile_plan=tile_plan)
+        with jax.named_scope("megba.lm_step"):
+            return step(s, pcg)
+
+    def step(s, pcg):
+        # The LM step past the PCG solve: step norms, the gain ratio's
+        # linearised cost (J.dx) and trial cost, accept/reject, and the
+        # trust-region update.
         dx_cam, dx_pt = pcg.dx_cam, pcg.dx_pt
 
         # ||dx|| <= eps2 (||x|| + eps1)  -> converged, don't apply
@@ -425,9 +436,11 @@ def lm_solve(
             uk = plans.use_kernels
             # Fused (gather + J.dx) on each side; Jp is PT-ordered, so
             # its [od] product rows hop to cam order for the final sum.
-            jc_dx = coupling_expand(dx_cam, s["Jc"], plans.cam, cd, uk)
-            jp_dx = plans.to_cam(
-                coupling_expand(dx_pt, s["Jp"], plans.pt, pd, uk))
+            jc_dx = coupling_expand(dx_cam, s["Jc"], plans.cam, cd, uk,
+                                    name="coupling_expand_cam_to_edge")
+            jp_dx = plans.to_cam(coupling_expand(
+                dx_pt, s["Jp"], plans.pt, pd, uk,
+                name="coupling_expand_pt_to_edge"))
             jdx = (jc_dx + jp_dx + s["r"]).astype(s["r"].dtype)
         else:
             dxc_e = jnp.take(dx_cam, cam_idx, axis=1)  # [cd, nE]

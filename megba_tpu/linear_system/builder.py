@@ -188,10 +188,10 @@ def build_schur_system(
                 f"plans requires float32 inputs, got {dtype}; "
                 "use the XLA path (plans=None) for other dtypes")
         hpp_rows, g_cam = jtj_grad_reduce(
-            Jc, r, plans.cam, plans.use_kernels)
+            Jc, r, plans.cam, plans.use_kernels, name="jtj_grad_reduce_cam")
         r_pt = plans.to_pt(r)
-        hll_acc = jnp.concatenate(
-            jtj_grad_reduce(Jp, r_pt, plans.pt, plans.use_kernels))
+        hll_acc = jnp.concatenate(jtj_grad_reduce(
+            Jp, r_pt, plans.pt, plans.use_kernels, name="jtj_grad_reduce_pt"))
     else:
         # Chunked scatter-add build: per chunk, form the outer-product
         # rows [d*d + d, chunk] and accumulate — the race-free functional

@@ -47,6 +47,7 @@ import sys
 from typing import Iterable, List
 
 from megba_tpu.observability.report import SolveReport
+from megba_tpu.utils.timing import top_level_seconds
 
 
 def load_reports(path: str) -> List[SolveReport]:
@@ -132,15 +133,14 @@ def format_report(rep: SolveReport, index: int = 0) -> str:
 
     if rep.phases:
         lines.append("   phases:")
-        total = 0.0
         for name in sorted(rep.phases,
                            key=lambda n: rep.phases[n]["total_s"],
                            reverse=True):
             ph = rep.phases[name]
             t_ms, c = ph["total_s"] * 1e3, ph["calls"]
-            total += ph["total_s"]
             lines.append(f"     {name}: {t_ms:.1f} ms / {c} calls "
                          f"= {t_ms / c:.2f} ms")
+        total = top_level_seconds(rep.phases)
         lines.append(f"     total: {total * 1e3:.1f} ms")
 
     if rep.memory:
@@ -166,7 +166,7 @@ def _report_latency(rep: SolveReport) -> float:
     if rep.fleet and rep.fleet.get("latency_s") is not None:
         return float(rep.fleet["latency_s"])
     if rep.phases:
-        return sum(ph.get("total_s", 0.0) for ph in rep.phases.values())
+        return top_level_seconds(rep.phases)
     return float("nan")
 
 

@@ -500,10 +500,11 @@ def _pad_table(table, num_blocks, block):
     jax.jit,
     static_argnames=("tile", "in_block", "out_block", "num_in_blocks",
                      "num_out_blocks", "w_in_major", "bf16_operands",
-                     "interpret"))
+                     "interpret", "name"))
 def _fused_w_call(W, table, in_local, out_local, tile_in, tile_out,
                   tile_first, *, tile, in_block, out_block, num_in_blocks,
-                  num_out_blocks, w_in_major, bf16_operands, interpret):
+                  num_out_blocks, w_in_major, bf16_operands, interpret,
+                  name="fused_coupling_w"):
     d_in = table.shape[0]
     d_out = W.shape[0] // d_in
     acc_dt = _acc_dtype(W.dtype, table.dtype)
@@ -520,16 +521,18 @@ def _fused_w_call(W, table, in_local, out_local, tile_in, tile_out,
             (d_out, num_out_blocks * out_block), acc_dt, in_local, W,
             table_p),
         interpret=interpret,
+        name=name,
     )(tile_in, tile_out, tile_first, in_local, out_local, W, table_p)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("tile", "in_block", "out_block", "num_in_blocks",
-                     "num_out_blocks", "bf16_operands", "interpret"))
+                     "num_out_blocks", "bf16_operands", "interpret", "name"))
 def _fused_j_call(Jin, Jout, table, in_local, out_local, tile_in, tile_out,
                   tile_first, *, tile, in_block, out_block, num_in_blocks,
-                  num_out_blocks, bf16_operands, interpret):
+                  num_out_blocks, bf16_operands, interpret,
+                  name="fused_coupling_j"):
     d_in = table.shape[0]
     od = Jin.shape[0] // d_in
     d_out = Jout.shape[0] // od
@@ -547,6 +550,7 @@ def _fused_j_call(Jin, Jout, table, in_local, out_local, tile_in, tile_out,
             (d_out, num_out_blocks * out_block), acc_dt, in_local, Jin,
             Jout, table_p),
         interpret=interpret,
+        name=name,
     )(tile_in, tile_out, tile_first, in_local, out_local, Jin, Jout,
       table_p)
 
@@ -563,17 +567,19 @@ def fused_coupling_apply(
     w_in_major: bool,
     bf16_operands: bool = False,
     interpret: bool = False,
+    name: str = "fused_coupling_w",
 ) -> jax.Array:
     """EXPLICIT fused matvec direction: [d_in, num_in] table ->
     [d_out, num_out_segments] sums.  `W_f` must already be in this
-    direction's fused slot order (`permute_rows`)."""
+    direction's fused slot order (`permute_rows`).  `name` names the
+    kernel (ops/segtiles.py, Pallas kernels)."""
     out = _fused_w_call(
         W_f, table, fplan.in_local, fplan.out_local, fplan.tile_in,
         fplan.tile_out, fplan.tile_first, tile=fplan.tile,
         in_block=fplan.in_block, out_block=fplan.out_block,
         num_in_blocks=fplan.num_in_blocks,
         num_out_blocks=fplan.num_out_blocks, w_in_major=w_in_major,
-        bf16_operands=bf16_operands, interpret=interpret)
+        bf16_operands=bf16_operands, interpret=interpret, name=name)
     return out[:, : fplan.num_out_segments]
 
 
@@ -584,6 +590,7 @@ def fused_coupling_apply_implicit(
     fplan: DeviceFusedPlan,
     bf16_operands: bool = False,
     interpret: bool = False,
+    name: str = "fused_coupling_j",
 ) -> jax.Array:
     """IMPLICIT fused matvec direction (Jc/Jp rows pre-permuted)."""
     out = _fused_j_call(
@@ -592,7 +599,7 @@ def fused_coupling_apply_implicit(
         in_block=fplan.in_block, out_block=fplan.out_block,
         num_in_blocks=fplan.num_in_blocks,
         num_out_blocks=fplan.num_out_blocks,
-        bf16_operands=bf16_operands, interpret=interpret)
+        bf16_operands=bf16_operands, interpret=interpret, name=name)
     return out[:, : fplan.num_out_segments]
 
 
@@ -613,6 +620,7 @@ def fused_single_block_apply(
     rows_out: Optional[jax.Array] = None,
     bf16_operands: bool = False,
     interpret: bool = False,
+    name: str = "fused_ring_step",
 ) -> jax.Array:
     """Degenerate fused plan: ONE input block (the whole table) and ONE
     output block — the 2-D mesh's ring-step contraction, where the
@@ -632,12 +640,12 @@ def fused_single_block_apply(
             rows, table, in_l, out_l, tile_zero, tile_zero, tile_first,
             tile=tile, in_block=table.shape[1], out_block=out_block,
             num_in_blocks=1, num_out_blocks=1, w_in_major=w_in_major,
-            bf16_operands=bf16_operands, interpret=interpret)
+            bf16_operands=bf16_operands, interpret=interpret, name=name)
     return _fused_j_call(
         rows, rows_out, table, in_l, out_l, tile_zero, tile_zero,
         tile_first, tile=tile, in_block=table.shape[1],
         out_block=out_block, num_in_blocks=1, num_out_blocks=1,
-        bf16_operands=bf16_operands, interpret=interpret)
+        bf16_operands=bf16_operands, interpret=interpret, name=name)
 
 
 # ---------------------------------------------------------------------------
@@ -648,8 +656,10 @@ FUSED_CAM_BLOCK = 512  # cameras per M^-1 apply grid step
 
 
 @functools.partial(
-    jax.jit, static_argnames=("cam_block", "bf16_operands", "interpret"))
-def _block_diag_call(Hrows, x, *, cam_block, bf16_operands, interpret):
+    jax.jit,
+    static_argnames=("cam_block", "bf16_operands", "interpret", "name"))
+def _block_diag_call(Hrows, x, *, cam_block, bf16_operands, interpret,
+                     name="fused_block_diag"):
     d = x.shape[0]
     nc = x.shape[1]
     nb = max(1, -(-nc // cam_block))
@@ -669,6 +679,7 @@ def _block_diag_call(Hrows, x, *, cam_block, bf16_operands, interpret):
         out_specs=pl.BlockSpec((d, cam_block), lambda i: (0, i)),
         out_shape=kernel_out_shape((d, nb * cam_block), acc_dt, Hrows, x),
         interpret=interpret,
+        name=name,
     )(Hrows, x)
     return out[:, :nc]
 
@@ -687,6 +698,7 @@ def fused_block_diag_apply(
     cam_block: int = FUSED_CAM_BLOCK,
     bf16_operands: bool = False,
     interpret: bool = False,
+    name: str = "fused_block_diag",
 ) -> jax.Array:
     """Fused M^-1 apply: one kernel pass over camera blocks, f32 (f64)
     accumulation; the bf16 arm multiplies bf16 x bf16 and upcasts —
@@ -694,7 +706,7 @@ def fused_block_diag_apply(
     cam_block = min(cam_block, max(8, x.shape[1]))
     return _block_diag_call(
         Hrows, x, cam_block=cam_block, bf16_operands=bf16_operands,
-        interpret=interpret).astype(x.dtype)
+        interpret=interpret, name=name).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -32,6 +32,7 @@ runs the same plan on CPU / in tests and under the sharded mesh path.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import os
@@ -233,6 +234,13 @@ def cross_perm(primary: TilePlan, secondary: TilePlan) -> np.ndarray:
 # ---------------------------------------------------------------------------
 # Pallas kernels
 # ---------------------------------------------------------------------------
+#
+# Every kernel takes a `name`, which its pallas_call gives the compiled
+# custom call: the profile's op event is `<name>.<n>` and the op-name
+# path ends `<name>/pallas_call`, under the caller's layer scope.  Call
+# sites name the kernel and its direction (`coupling_expand_cam_to_edge`,
+# `coupling_reduce_edge_to_pt`, `jtj_grad_reduce_cam`, ...), so one
+# kernel can be followed across program edits, which renumber the HLO.
 
 
 def _reduce_kernel(tb_ref, tf_ref, local_ref, data_ref, out_ref, *, block):
@@ -260,10 +268,11 @@ def _reduce_kernel(tb_ref, tf_ref, local_ref, data_ref, out_ref, *, block):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("tile", "block", "num_blocks", "interpret"))
+    jax.jit,
+    static_argnames=("tile", "block", "num_blocks", "interpret", "name"))
 def _tile_reduce_call(
     data, local, tile_block, tile_first, *, tile, block, num_blocks,
-    interpret,
+    interpret, name="tile_reduce",
 ):
     F = data.shape[0]
     n_tiles = tile_block.shape[0]
@@ -283,11 +292,13 @@ def _tile_reduce_call(
         out_shape=fused_ops.kernel_out_shape(
             (F, num_blocks * block), jnp.float32, local, data),
         interpret=interpret,
+        name=name,
     )(tile_block, tile_first, local, data)
 
 
 def tile_reduce(
-    data: jax.Array, plan: DevicePlan, interpret: bool = False
+    data: jax.Array, plan: DevicePlan, interpret: bool = False,
+    name: str = "tile_reduce",
 ) -> jax.Array:
     """Sum plan-ordered [F, n_slots] rows into [F, num_segments].
 
@@ -299,7 +310,7 @@ def tile_reduce(
     out = _tile_reduce_call(
         data, plan.local, plan.tile_block, plan.tile_first,
         tile=plan.tile, block=plan.block, num_blocks=plan.num_blocks,
-        interpret=interpret,
+        interpret=interpret, name=name,
     )
     return out[:, : plan.num_segments].astype(data.dtype)
 
@@ -319,9 +330,11 @@ def _expand_kernel(tb_ref, local_ref, table_ref, out_ref, *, block):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("tile", "block", "num_blocks", "interpret"))
+    jax.jit,
+    static_argnames=("tile", "block", "num_blocks", "interpret", "name"))
 def _tile_expand_call(
-    table, local, tile_block, *, tile, block, num_blocks, interpret
+    table, local, tile_block, *, tile, block, num_blocks, interpret,
+    name="tile_expand",
 ):
     F = table.shape[0]
     n_tiles = tile_block.shape[0]
@@ -342,11 +355,13 @@ def _tile_expand_call(
         out_shape=fused_ops.kernel_out_shape(
             (F, n_tiles * tile), table.dtype, local, table_p),
         interpret=interpret,
+        name=name,
     )(tile_block, local, table_p)
 
 
 def tile_expand(
-    table: jax.Array, plan: DevicePlan, interpret: bool = False
+    table: jax.Array, plan: DevicePlan, interpret: bool = False,
+    name: str = "tile_expand",
 ) -> jax.Array:
     """Gather segment rows to plan-ordered edges: [F, nS] -> [F, n_slots].
 
@@ -356,7 +371,7 @@ def tile_expand(
     return _tile_expand_call(
         table, plan.local, plan.tile_block,
         tile=plan.tile, block=plan.block, num_blocks=plan.num_blocks,
-        interpret=interpret,
+        interpret=interpret, name=name,
     )
 
 
@@ -382,20 +397,22 @@ def expand_fallback(table: jax.Array, plan: DevicePlan) -> jax.Array:
 
 
 def seg_reduce(
-    data: jax.Array, plan: DevicePlan, use_kernels: bool
+    data: jax.Array, plan: DevicePlan, use_kernels: bool,
+    name: str = "tile_reduce",
 ) -> jax.Array:
     """Plan-ordered rows -> per-segment sums; kernel or XLA fallback."""
     if use_kernels:
-        return tile_reduce(data, plan)
+        return tile_reduce(data, plan, name=name)
     return reduce_fallback(data, plan)
 
 
 def seg_expand(
-    table: jax.Array, plan: DevicePlan, use_kernels: bool
+    table: jax.Array, plan: DevicePlan, use_kernels: bool,
+    name: str = "tile_expand",
 ) -> jax.Array:
     """Per-segment rows -> plan-ordered per-edge rows (gather)."""
     if use_kernels:
-        return tile_expand(table, plan)
+        return tile_expand(table, plan, name=name)
     return expand_fallback(table, plan)
 
 
@@ -452,10 +469,11 @@ def _jtj_kernel(tb_ref, tf_ref, local_ref, j_ref, r_ref, out_ref,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("d", "od", "tile", "block", "num_blocks", "interpret"))
+    static_argnames=("d", "od", "tile", "block", "num_blocks", "interpret",
+                     "name"))
 def _jtj_reduce_call(
     J, r, local, tile_block, tile_first, *, d, od, tile, block, num_blocks,
-    interpret,
+    interpret, name="jtj_grad_reduce",
 ):
     n_tiles = tile_block.shape[0]
     feat = d * d + d
@@ -476,6 +494,7 @@ def _jtj_reduce_call(
         out_shape=fused_ops.kernel_out_shape(
             (feat, num_blocks * block), jnp.float32, local, J, r),
         interpret=interpret,
+        name=name,
     )(tile_block, tile_first, local, J, r)
 
 
@@ -485,6 +504,7 @@ def jtj_grad_reduce(
     plan: DevicePlan,
     use_kernels: bool,
     interpret: bool = False,
+    name: str = "jtj_grad_reduce",
 ) -> Tuple[jax.Array, jax.Array]:
     """Fused block-diagonal Hessian + gradient for one vertex kind.
 
@@ -498,7 +518,7 @@ def jtj_grad_reduce(
         out = _jtj_reduce_call(
             J, r, plan.local, plan.tile_block, plan.tile_first,
             d=d, od=od, tile=plan.tile, block=plan.block,
-            num_blocks=plan.num_blocks, interpret=interpret)
+            num_blocks=plan.num_blocks, interpret=interpret, name=name)
         out = out[:, : plan.num_segments].astype(J.dtype)
     else:
         out = _jtj_fallback_chunked(J, r, plan, d, od)
@@ -608,9 +628,10 @@ def _expand_matvec_kernel(tb_ref, local_ref, j_ref, table_ref, out_ref,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("d", "tile", "block", "num_blocks", "interpret"))
+    static_argnames=("d", "tile", "block", "num_blocks", "interpret", "name"))
 def _expand_matvec_call(
-    J, table, local, tile_block, *, d, tile, block, num_blocks, interpret
+    J, table, local, tile_block, *, d, tile, block, num_blocks, interpret,
+    name="coupling_expand",
 ):
     od = J.shape[0] // d
     n_tiles = tile_block.shape[0]
@@ -632,6 +653,7 @@ def _expand_matvec_call(
         out_shape=fused_ops.kernel_out_shape(
             (od, n_tiles * tile), jnp.float32, local, J, table_p),
         interpret=interpret,
+        name=name,
     )(tile_block, local, J, table_p)
 
 
@@ -675,10 +697,10 @@ def _matvec_reduce_kernel(tb_ref, tf_ref, local_ref, j_ref, u_ref, out_ref,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("d", "tile", "block", "num_blocks", "interpret"))
+    static_argnames=("d", "tile", "block", "num_blocks", "interpret", "name"))
 def _matvec_reduce_call(
     J, u, local, tile_block, tile_first, *, d, tile, block, num_blocks,
-    interpret,
+    interpret, name="coupling_reduce",
 ):
     n_tiles = tile_block.shape[0]
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -698,6 +720,7 @@ def _matvec_reduce_call(
         out_shape=fused_ops.kernel_out_shape(
             (d, num_blocks * block), jnp.float32, local, J, u),
         interpret=interpret,
+        name=name,
     )(tile_block, tile_first, local, J, u)
 
 
@@ -708,6 +731,7 @@ def coupling_expand(
     d: int,
     use_kernels: bool,
     interpret: bool = False,
+    name: str = "coupling_expand",
 ) -> jax.Array:
     """u[o] = sum_a J[o*d+a] * table[a, seg]  -> [od, n_slots] rows.
 
@@ -718,7 +742,7 @@ def coupling_expand(
         return _expand_matvec_call(
             J, table.astype(jnp.float32), plan.local, plan.tile_block,
             d=d, tile=plan.tile, block=plan.block,
-            num_blocks=plan.num_blocks, interpret=interpret)
+            num_blocks=plan.num_blocks, interpret=interpret, name=name)
     od = J.shape[0] // d
     pe = expand_fallback(table, plan)
     return jnp.stack([
@@ -734,6 +758,7 @@ def coupling_reduce(
     d: int,
     use_kernels: bool,
     interpret: bool = False,
+    name: str = "coupling_reduce",
 ) -> jax.Array:
     """out[b, seg] = sum_edges sum_o J[o*d+b] * u[o]  -> [d, nS].
 
@@ -743,7 +768,7 @@ def coupling_reduce(
         out = _matvec_reduce_call(
             J, u, plan.local, plan.tile_block, plan.tile_first,
             d=d, tile=plan.tile, block=plan.block,
-            num_blocks=plan.num_blocks, interpret=interpret)
+            num_blocks=plan.num_blocks, interpret=interpret, name=name)
         return out[:, : plan.num_segments]
     od = u.shape[0]
     te = jnp.stack([
@@ -1069,6 +1094,23 @@ def _plan_cache_put(key, value):
     _PLAN_CACHE[key] = value
 
 
+def _cached_plan(tag, arrays, params, build, timer=None):
+    """`build()` behind the host plan cache, keyed by `tag`, the digest
+    of each of `arrays` (None stays None) and `params`.  Returns
+    (value, cache_hit); the digest and the lookup are `timer`'s
+    `plan.key` sub-phase (utils.timing.PhaseTimer)."""
+    with (timer.phase("plan.key") if timer is not None
+          else contextlib.nullcontext()):
+        key = (tag, *(None if a is None else _array_digest(a)
+                      for a in arrays), *params)
+        hit = _plan_cache_get(key)
+    if hit is not None:
+        return hit, True
+    value = build()
+    _plan_cache_put(key, value)
+    return value, False
+
+
 def cached_dual_plans(
     cam_idx: np.ndarray,
     pt_idx: np.ndarray,
@@ -1079,6 +1121,7 @@ def cached_dual_plans(
     tile_pt: int = DEFAULT_TILE_PT,
     block_pt: int = DEFAULT_BLOCK_PT,
     use_kernels: Optional[bool] = None,
+    timer=None,
 ):
     """`make_dual_plans` behind the host plan cache, its DualPlans placed
     once on the default device (the single-device solve's).
@@ -1089,18 +1132,17 @@ def cached_dual_plans(
     """
     if use_kernels is None:
         use_kernels = probe_kernels()
-    key = ("single", _array_digest(cam_idx), _array_digest(pt_idx),
-           int(num_cameras), int(num_points),
-           tile_cam, block_cam, tile_pt, block_pt, use_kernels)
-    hit = _plan_cache_get(key)
-    if hit is not None:
-        return hit, True
-    plan_c, dual = make_dual_plans(
-        cam_idx, pt_idx, num_cameras, num_points,
-        tile_cam, block_cam, tile_pt, block_pt, use_kernels)
-    value = (plan_c, jax.device_put(dual))
-    _plan_cache_put(key, value)
-    return value, False
+
+    def build():
+        plan_c, dual = make_dual_plans(
+            cam_idx, pt_idx, num_cameras, num_points,
+            tile_cam, block_cam, tile_pt, block_pt, use_kernels)
+        return plan_c, jax.device_put(dual)
+
+    return _cached_plan(
+        "single", (cam_idx, pt_idx),
+        (int(num_cameras), int(num_points),
+         tile_cam, block_cam, tile_pt, block_pt, use_kernels), build, timer)
 
 
 def cached_sharded_dual_plans(
@@ -1114,23 +1156,21 @@ def cached_sharded_dual_plans(
     tile_pt: int = DEFAULT_TILE_PT,
     block_pt: int = DEFAULT_BLOCK_PT,
     use_kernels: Optional[bool] = None,
+    timer=None,
 ):
     """`make_sharded_dual_plans` behind the host plan cache.
 
     Returns ((perms, masks, cam_segs, DualPlans), cache_hit)."""
     if use_kernels is None:
         use_kernels = probe_kernels()
-    key = ("sharded", _array_digest(cam_idx), _array_digest(pt_idx),
-           int(num_cameras), int(num_points), int(world_size),
-           tile_cam, block_cam, tile_pt, block_pt, use_kernels)
-    hit = _plan_cache_get(key)
-    if hit is not None:
-        return hit, True
-    value = make_sharded_dual_plans(
-        cam_idx, pt_idx, num_cameras, num_points, world_size,
-        tile_cam, block_cam, tile_pt, block_pt, use_kernels)
-    _plan_cache_put(key, value)
-    return value, False
+    return _cached_plan(
+        "sharded", (cam_idx, pt_idx),
+        (int(num_cameras), int(num_points), int(world_size),
+         tile_cam, block_cam, tile_pt, block_pt, use_kernels),
+        lambda: make_sharded_dual_plans(
+            cam_idx, pt_idx, num_cameras, num_points, world_size,
+            tile_cam, block_cam, tile_pt, block_pt, use_kernels),
+        timer)
 
 
 # ---------------------------------------------------------------------------
@@ -1424,20 +1464,16 @@ def cached_cluster_plan(
     planned indices), but it is part of the key by contract: a
     SolverOption knob flip must never be able to serve a stale plan
     from the LRU, including under future plans that do consume it."""
-    key = ("cluster", _array_digest(np.asarray(cam_idx)),
-           _array_digest(np.asarray(pt_idx)),
-           (None if mask is None
-            else _array_digest(np.asarray(mask) > 0)),
-           int(num_cameras), int(num_points), int(target),
-           int(world_size), float(smooth_omega))
-    hit = _plan_cache_get(key)
-    if hit is not None:
-        return hit, True
-    plan = build_cluster_plan(cam_idx, pt_idx, num_cameras, num_points,
-                              target, mask, world_size=world_size)
-    value = (plan, device_cluster_plan(plan))
-    _plan_cache_put(key, value)
-    return value, False
+    def build():
+        plan = build_cluster_plan(cam_idx, pt_idx, num_cameras, num_points,
+                                  target, mask, world_size=world_size)
+        return plan, device_cluster_plan(plan)
+
+    return _cached_plan(
+        "cluster",
+        (cam_idx, pt_idx, None if mask is None else np.asarray(mask) > 0),
+        (int(num_cameras), int(num_points), int(target), int(world_size),
+         float(smooth_omega)), build)
 
 
 # ---------------------------------------------------------------------------
@@ -1584,23 +1620,19 @@ def cached_multilevel_plan(
     so flipping any SolverOption preconditioner knob can never serve a
     stale hierarchy from the LRU (the coarse level count and cluster
     shapes are baked into the compiled program's operand shapes)."""
-    key = ("multilevel", _array_digest(np.asarray(cam_idx)),
-           _array_digest(np.asarray(pt_idx)),
-           (None if mask is None
-            else _array_digest(np.asarray(mask) > 0)),
-           int(num_cameras), int(num_points), int(target),
-           int(world_size), float(coarsen_factor), int(max_levels),
-           float(smooth_omega))
-    hit = _plan_cache_get(key)
-    if hit is not None:
-        return hit, True
-    plan = build_multilevel_plan(
-        cam_idx, pt_idx, num_cameras, num_points, target, mask,
-        world_size=world_size, coarsen_factor=coarsen_factor,
-        max_levels=max_levels)
-    value = (plan, device_multilevel_plan(plan))
-    _plan_cache_put(key, value)
-    return value, False
+    def build():
+        plan = build_multilevel_plan(
+            cam_idx, pt_idx, num_cameras, num_points, target, mask,
+            world_size=world_size, coarsen_factor=coarsen_factor,
+            max_levels=max_levels)
+        return plan, device_multilevel_plan(plan)
+
+    return _cached_plan(
+        "multilevel",
+        (cam_idx, pt_idx, None if mask is None else np.asarray(mask) > 0),
+        (int(num_cameras), int(num_points), int(target), int(world_size),
+         float(coarsen_factor), int(max_levels), float(smooth_omega)),
+        build)
 
 
 # ---------------------------------------------------------------------------
@@ -1885,25 +1917,23 @@ def cached_camera_tile_plan(
     edge_shards: int,
     cam_blocks: int,
     quantum: int = 0,
+    timer=None,
 ):
     """`build_camera_tile_plan` behind the host plan cache.
 
     Returns ((CameraTilePlan, DeviceCameraTilePlan), cache_hit), keyed
     by a blake2b content fingerprint of the index arrays plus EVERY
     geometry knob, exactly like the tile/cluster plans."""
-    key = ("mesh2d", _array_digest(np.asarray(cam_idx)),
-           _array_digest(np.asarray(pt_idx)),
-           int(num_cameras), int(num_points), int(edge_shards),
-           int(cam_blocks), int(quantum))
-    hit = _plan_cache_get(key)
-    if hit is not None:
-        return hit, True
-    plan = build_camera_tile_plan(
-        cam_idx, pt_idx, num_cameras, num_points, edge_shards,
-        cam_blocks, quantum=quantum)
-    value = (plan, device_camera_tile_plan(plan))
-    _plan_cache_put(key, value)
-    return value, False
+    def build():
+        plan = build_camera_tile_plan(
+            cam_idx, pt_idx, num_cameras, num_points, edge_shards,
+            cam_blocks, quantum=quantum)
+        return plan, device_camera_tile_plan(plan)
+
+    return _cached_plan(
+        "mesh2d", (cam_idx, pt_idx),
+        (int(num_cameras), int(num_points), int(edge_shards),
+         int(cam_blocks), int(quantum)), build, timer)
 
 
 # Kernel-vs-fallback agreement bound, relative to the fallback's

@@ -97,6 +97,10 @@ def _build_single_solve(residual_jac_fn, option, keys, verbose, cam_sorted):
 _cached_single_solve = functools.lru_cache(maxsize=64)(_build_single_solve)
 
 
+# One host span from entry to return (the program is then enqueued, not
+# finished): the profiler's view of every call's host work, around the
+# PhaseTimer phases inside it.
+@functools.partial(jax.profiler.annotate_function, name="megba.flat_solve")
 def flat_solve(
     residual_jac_fn,
     cameras: np.ndarray,
@@ -173,11 +177,15 @@ def flat_solve(
     `timer` (utils.timing.PhaseTimer, fresh one by default) accumulates
     the host-side phase wall clocks (lowering / sort / plan / program /
     dispatch — "dispatch" includes jit tracing+compilation on the first
-    call of a configuration).  With telemetry enabled
-    (MEGBA_TELEMETRY=<path> or `option.telemetry`) an extra blocking
-    "execute" phase is timed and a SolveReport JSONL line is appended;
-    with it disabled the solve stays fully asynchronous and the sink
-    module is never even imported.
+    call of a configuration), and inside them the sub-phases plan.key
+    (index digest and plan-cache lookup), plan.gather (edges into plan
+    order), lowering.transpose (feature-major operands), and on one
+    device dispatch.upload (index and mask operands) and dispatch.call
+    (the jitted call, host operands' transfer included).  With
+    telemetry enabled (MEGBA_TELEMETRY=<path> or `option.telemetry`) an
+    extra blocking "execute" phase is timed and a SolveReport JSONL line
+    is appended; with it disabled the solve stays fully asynchronous and
+    the sink module is never even imported.
 
     `elastic_report` (a dict, robustness.elastic.ElasticMonitor.
     report_block()) attaches the elastic-distribution ledger to this
@@ -410,27 +418,28 @@ def flat_solve(
             evict0 = plan_cache_evictions()
             (tplan, tile_plan_j), plan_hit = cached_camera_tile_plan(
                 cam_idx, pt_idx, cameras.shape[0], points.shape[0],
-                n_shards, n_blocks)
+                n_shards, n_blocks, timer=timer)
             if plan_hit:
                 timer.count_event("plan_cache_hit")
             evicted = plan_cache_evictions() - evict0
             if evicted:
                 timer.count_event("plan_cache_evict", evicted)
-            perm, pmask = tplan.perm, tplan.mask
-            obs = obs[perm] * pmask[:, None].astype(dtype)
-            cam_idx = tplan.cam_idx
-            pt_idx = tplan.pt_idx
-            mask = pmask.astype(dtype)
-            if em is not None:
-                # Padding slots repeat caller edge 0 under pmask 0, so
-                # the soft-delete mask multiplies in exactly.
-                mask = mask * em[perm]
-            if sqrt_info is not None:
-                sqrt_info = np.asarray(sqrt_info)[perm]
-            if fault_edge is not None:
-                from megba_tpu.robustness.faults import lower_edge_vector
+            with timer.phase("plan.gather"):
+                perm, pmask = tplan.perm, tplan.mask
+                obs = obs[perm] * pmask[:, None].astype(dtype)
+                cam_idx = tplan.cam_idx
+                pt_idx = tplan.pt_idx
+                mask = pmask.astype(dtype)
+                if em is not None:
+                    # Padding slots repeat caller edge 0 under pmask 0, so
+                    # the soft-delete mask multiplies in exactly.
+                    mask = mask * em[perm]
+                if sqrt_info is not None:
+                    sqrt_info = np.asarray(sqrt_info)[perm]
+                if fault_edge is not None:
+                    from megba_tpu.robustness.faults import lower_edge_vector
 
-                fault_edge = lower_edge_vector(fault_edge, perm, pmask)
+                    fault_edge = lower_edge_vector(fault_edge, perm, pmask)
             n_padded = obs.shape[0]
             tiles_info = {
                 "plan": "mesh2d",
@@ -452,42 +461,44 @@ def flat_solve(
             evict0 = plan_cache_evictions()
             (perms, masks, cam_segs, plans), plan_hit = (
                 cached_sharded_dual_plans(
-                    cam_idx, pt_idx, cameras.shape[0], points.shape[0], ws))
+                    cam_idx, pt_idx, cameras.shape[0], points.shape[0], ws,
+                    timer=timer))
             if plan_hit:
                 timer.count_event("plan_cache_hit")
             evicted = plan_cache_evictions() - evict0
             if evicted:
                 timer.count_event("plan_cache_evict", evicted)
-            obs = np.concatenate([
-                obs[perms[k]] * masks[k][:, None].astype(dtype)
-                for k in range(ws)])
-            # cam_segs keeps each shard's cam stream non-decreasing
-            # (padding carries the block's running-max camera) so the
-            # sorted-scatter promise downstream stays honest; masked
-            # slots contribute zeros.
-            cam_idx_sh = cam_segs.reshape(-1).astype(np.int32)
-            pt_idx_sh = np.concatenate([
-                np.where(masks[k] > 0, pt_idx[perms[k]], 0)
-                for k in range(ws)]).astype(np.int32)
-            if sqrt_info is not None:
-                sqrt_info = np.concatenate(
-                    [np.asarray(sqrt_info)[perms[k]] for k in range(ws)])
-            if fault_edge is not None:
-                from megba_tpu.robustness.faults import lower_edge_vector
+            with timer.phase("plan.gather"):
+                obs = np.concatenate([
+                    obs[perms[k]] * masks[k][:, None].astype(dtype)
+                    for k in range(ws)])
+                # cam_segs keeps each shard's cam stream non-decreasing
+                # (padding carries the block's running-max camera) so the
+                # sorted-scatter promise downstream stays honest; masked
+                # slots contribute zeros.
+                cam_idx_sh = cam_segs.reshape(-1).astype(np.int32)
+                pt_idx_sh = np.concatenate([
+                    np.where(masks[k] > 0, pt_idx[perms[k]], 0)
+                    for k in range(ws)]).astype(np.int32)
+                if sqrt_info is not None:
+                    sqrt_info = np.concatenate(
+                        [np.asarray(sqrt_info)[perms[k]] for k in range(ws)])
+                if fault_edge is not None:
+                    from megba_tpu.robustness.faults import lower_edge_vector
 
-                fault_edge = np.concatenate([
-                    lower_edge_vector(fault_edge, perms[k], masks[k])
-                    for k in range(ws)])
-            cam_idx, pt_idx = cam_idx_sh, pt_idx_sh
-            if em is not None:
-                # Each shard's slot stream permutes the caller's edge
-                # order; the soft-delete mask rides the same perms and
-                # lands multiplicatively on the shard padding mask.
-                mask = np.concatenate([
-                    masks[k].astype(dtype) * em[perms[k]]
-                    for k in range(ws)])
-            else:
-                mask = masks.reshape(-1).astype(dtype)
+                    fault_edge = np.concatenate([
+                        lower_edge_vector(fault_edge, perms[k], masks[k])
+                        for k in range(ws)])
+                cam_idx, pt_idx = cam_idx_sh, pt_idx_sh
+                if em is not None:
+                    # Each shard's slot stream permutes the caller's edge
+                    # order; the soft-delete mask rides the same perms and
+                    # lands multiplicatively on the shard padding mask.
+                    mask = np.concatenate([
+                        masks[k].astype(dtype) * em[perms[k]]
+                        for k in range(ws)])
+                else:
+                    mask = masks.reshape(-1).astype(dtype)
             n_padded = obs.shape[0]
     elif use_tiled:
         # Tiled lowering: the cam plan's slot order IS the edge axis from
@@ -500,25 +511,27 @@ def flat_solve(
         with timer.phase("plan"):
             evict0 = plan_cache_evictions()
             (plan_c, plans), plan_hit = cached_dual_plans(
-                cam_idx, pt_idx, cameras.shape[0], points.shape[0])
+                cam_idx, pt_idx, cameras.shape[0], points.shape[0],
+                timer=timer)
             if plan_hit:
                 timer.count_event("plan_cache_hit")
             evicted = plan_cache_evictions() - evict0
             if evicted:
                 timer.count_event("plan_cache_evict", evicted)
-            perm, pmask = plan_c.perm, plan_c.mask
-            obs = obs[perm] * pmask[:, None].astype(dtype)
-            cam_idx = plan_c.seg
-            pt_idx = np.where(pmask > 0, pt_idx[perm], 0).astype(np.int32)
-            mask = pmask.astype(dtype)
-            if em is not None:
-                mask = mask * em[perm]
-            if sqrt_info is not None:
-                sqrt_info = np.asarray(sqrt_info)[perm]
-            if fault_edge is not None:
-                from megba_tpu.robustness.faults import lower_edge_vector
+            with timer.phase("plan.gather"):
+                perm, pmask = plan_c.perm, plan_c.mask
+                obs = obs[perm] * pmask[:, None].astype(dtype)
+                cam_idx = plan_c.seg
+                pt_idx = np.where(pmask > 0, pt_idx[perm], 0).astype(np.int32)
+                mask = pmask.astype(dtype)
+                if em is not None:
+                    mask = mask * em[perm]
+                if sqrt_info is not None:
+                    sqrt_info = np.asarray(sqrt_info)[perm]
+                if fault_edge is not None:
+                    from megba_tpu.robustness.faults import lower_edge_vector
 
-                fault_edge = lower_edge_vector(fault_edge, perm, pmask)
+                    fault_edge = lower_edge_vector(fault_edge, perm, pmask)
             n_padded = obs.shape[0]
             if fused:
                 # Fused edge-pipeline bucket plans, one per matvec
@@ -537,23 +550,24 @@ def flat_solve(
                     cameras.shape[0], points.shape[0])
                 plans = _dc.replace(
                     plans, fused_to_pt=dfp_tp, fused_to_cam=dfp_tc)
-            # Streaming-reuse + occupancy metrics of the planned stream
-            # (SolveReport.tiles): the honest per-solve attribution of
-            # what the tile ordering — and the fused kernels, when on —
-            # actually have to work with.
-            from megba_tpu.ops.fused import fused_plan_summary
-            from megba_tpu.ops.segtiles import edge_stream_reuse
+            if telemetry:
+                # Streaming-reuse + occupancy metrics of the planned
+                # stream (SolveReport.tiles, which only the report
+                # reads): what the tile ordering — and the fused
+                # kernels, when on — actually have to work with.
+                from megba_tpu.ops.fused import fused_plan_summary
+                from megba_tpu.ops.segtiles import edge_stream_reuse
 
-            tiles_info = {
-                "plan": "tiled_1d",
-                "occupancy": round(
-                    plan_c.n_edges / max(1, plan_c.n_slots), 4),
-                **edge_stream_reuse(cam_idx, pt_idx, plan_c.block,
-                                    plans.pt.block, mask=pmask),
-            }
-            if fused:
-                tiles_info["fused_to_pt"] = fused_plan_summary(fp_tp)
-                tiles_info["fused_to_cam"] = fused_plan_summary(fp_tc)
+                tiles_info = {
+                    "plan": "tiled_1d",
+                    "occupancy": round(
+                        plan_c.n_edges / max(1, plan_c.n_slots), 4),
+                    **edge_stream_reuse(cam_idx, pt_idx, plan_c.block,
+                                        plans.pt.block, mask=pmask),
+                }
+                if fused:
+                    tiles_info["fused_to_pt"] = fused_plan_summary(fp_tp)
+                    tiles_info["fused_to_cam"] = fused_plan_summary(fp_tc)
     else:
         with timer.phase("sort"):
             if not is_cam_sorted(cam_idx):
@@ -659,7 +673,7 @@ def flat_solve(
     # once on call — and the multi-process path builds global arrays
     # straight from host memory (a premature jnp.asarray would cost a
     # device->host->device round trip per operand there).
-    with timer.phase("lowering"):
+    with timer.phase("lowering"), timer.phase("lowering.transpose"):
         cameras_fm = np.ascontiguousarray(cameras.T)
         points_fm = np.ascontiguousarray(points.T)
         obs_fm = np.ascontiguousarray(obs.T)
@@ -719,14 +733,17 @@ def flat_solve(
     # of the timed dispatch cost, as they always were (telemetry phase
     # breakdowns must stay comparable across artifacts).
     with timer.phase("dispatch"):
-        call_args = (
-            cameras_fm, points_fm, obs_fm,
-            jnp.asarray(cam_idx), jnp.asarray(pt_idx), jnp.asarray(mask),
-            jnp.asarray(ir, dtype), jnp.asarray(iv, dtype),
-            jnp.asarray(next_verbose_token(), jnp.int32), plans, *extras)
+        with timer.phase("dispatch.upload"):
+            call_args = (
+                cameras_fm, points_fm, obs_fm,
+                jnp.asarray(cam_idx), jnp.asarray(pt_idx),
+                jnp.asarray(mask), jnp.asarray(ir, dtype),
+                jnp.asarray(iv, dtype),
+                jnp.asarray(next_verbose_token(), jnp.int32), plans, *extras)
         if lower_only:
             return jitted.lower(*call_args)
-        result = jitted(*call_args)
+        with timer.phase("dispatch.call"):
+            result = jitted(*call_args)
     result = _result_to_edge_major(result)
     _maybe_emit_report(telemetry, report_option, result, timer,
                        problem_shape, elastic=elastic_report,

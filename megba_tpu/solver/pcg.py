@@ -302,12 +302,14 @@ def make_coupling_matvecs(
             def hlp(p_cam: jax.Array) -> jax.Array:
                 return psum(_fused.fused_coupling_apply(
                     W_tp, p_cam, fp_tp, w_in_major=True,
-                    bf16_operands=bf16_ops, interpret=interp))
+                    bf16_operands=bf16_ops, interpret=interp,
+                    name="fused_coupling_w_cam_to_pt"))
 
             def hpl(q_pt: jax.Array) -> jax.Array:
                 return psum(_fused.fused_coupling_apply(
                     W_tc, q_pt, fp_tc, w_in_major=False,
-                    bf16_operands=bf16_ops, interpret=interp))
+                    bf16_operands=bf16_ops, interpret=interp,
+                    name="fused_coupling_w_pt_to_cam"))
 
         else:
             # The tiled lowering stores Jp in PT-slot order (the
@@ -325,12 +327,14 @@ def make_coupling_matvecs(
             def hlp(p_cam: jax.Array) -> jax.Array:
                 return psum(_fused.fused_coupling_apply_implicit(
                     Jc_tp, Jp_tp, p_cam, fp_tp,
-                    bf16_operands=bf16_ops, interpret=interp))
+                    bf16_operands=bf16_ops, interpret=interp,
+                    name="fused_coupling_j_cam_to_pt"))
 
             def hpl(q_pt: jax.Array) -> jax.Array:
                 return psum(_fused.fused_coupling_apply_implicit(
                     Jp_tc, Jc_tc, q_pt, fp_tc,
-                    bf16_operands=bf16_ops, interpret=interp))
+                    bf16_operands=bf16_ops, interpret=interp,
+                    name="fused_coupling_j_pt_to_cam"))
 
         return hpl, hlp
 
@@ -343,17 +347,21 @@ def make_coupling_matvecs(
             def hlp(p_cam: jax.Array) -> jax.Array:
                 cd = p_cam.shape[0]
                 pd = cdpd // cd
-                pe = vec(seg_expand(p_cam, plans.cam, uk))  # [cd, nCamSlots]
+                pe = vec(seg_expand(p_cam, plans.cam, uk,
+                                    name="tile_expand_cam_to_edge"))
                 te = _edge_cam_to_pt_explicit(W, pe, cd, pd, up, acc)
-                return psum(seg_reduce(plans.to_pt(te), plans.pt, uk))
+                return psum(seg_reduce(plans.to_pt(te), plans.pt, uk,
+                                       name="tile_reduce_edge_to_pt"))
 
             def hpl(q_pt: jax.Array) -> jax.Array:
                 pd = q_pt.shape[0]
                 cd = cdpd // pd
-                qe = vec(plans.to_cam(
-                    seg_expand(q_pt, plans.pt, uk)))  # [pd, nCamSlots]
+                qe = vec(plans.to_cam(seg_expand(
+                    q_pt, plans.pt, uk,
+                    name="tile_expand_pt_to_edge")))  # [pd, nCamSlots]
                 te = _edge_pt_to_cam_explicit(W, qe, cd, pd, up, acc)
-                return psum(seg_reduce(te, plans.cam, uk))
+                return psum(seg_reduce(te, plans.cam, uk,
+                                       name="tile_reduce_edge_to_cam"))
 
         else:
             from megba_tpu.ops.segtiles import (
@@ -371,17 +379,23 @@ def make_coupling_matvecs(
                 # [od] rows hop to pt order; J^T u reduces to points
                 # (fused matvec+reduce).  The expanded [cd]/[pd] per-edge
                 # rows never touch HBM.
-                u = coupling_expand(p_cam, Jc, plans.cam, cd, uk)
+                u = coupling_expand(p_cam, Jc, plans.cam, cd, uk,
+                                    name="coupling_expand_cam_to_edge")
                 u_pt = plans.to_pt(u)
-                return psum(coupling_reduce(Jp, u_pt, plans.pt, pd, uk))
+                return psum(coupling_reduce(
+                    Jp, u_pt, plans.pt, pd, uk,
+                    name="coupling_reduce_edge_to_pt"))
 
             def hpl(q_pt: jax.Array) -> jax.Array:
                 pd = q_pt.shape[0]
                 od = opd // pd
                 cd = ocd // od
-                u = coupling_expand(q_pt, Jp, plans.pt, pd, uk)
+                u = coupling_expand(q_pt, Jp, plans.pt, pd, uk,
+                                    name="coupling_expand_pt_to_edge")
                 u_cam = plans.to_cam(u)
-                return psum(coupling_reduce(Jc, u_cam, plans.cam, cd, uk))
+                return psum(coupling_reduce(
+                    Jc, u_cam, plans.cam, cd, uk,
+                    name="coupling_reduce_edge_to_cam"))
 
         return hpl, hlp
 
@@ -599,14 +613,16 @@ def make_matvec_2d(
                     step = _fused.fused_single_block_apply(
                         Wg, cur, ptl, cl, out_block=Tc,
                         w_in_major=False, bf16_operands=bf16_ops,
-                        interpret=fused_interp)
+                        interpret=fused_interp,
+                        name="fused_ring_w_pt_to_cam")
                 else:
                     Jcg = jnp.take(Jc, slot, axis=1)
                     Jpg = jnp.take(Jp, slot, axis=1) * mkd
                     step = _fused.fused_single_block_apply(
                         Jpg, cur, ptl, cl, out_block=Tc,
                         rows_out=Jcg, bf16_operands=bf16_ops,
-                        interpret=fused_interp)
+                        interpret=fused_interp,
+                        name="fused_ring_j_pt_to_cam")
                 tile_acc = tile_acc + step.astype(p.dtype)
                 cur = nxt
                 continue
@@ -1191,146 +1207,153 @@ def schur_pcg_solve(
                                  preconditioner, precond, neumann_order,
                                  smooth_omega, bf16, bf16_collectives,
                                  fused_kernels))
-    num_cameras = system.Hpp.shape[0]
-    num_points = system.Hll.shape[1]
-    pd = int(round(system.Hll.shape[0] ** 0.5))
+    # Everything before the CG loop (damping, equilibration, Hll^-1,
+    # the preconditioner build, the reduced RHS) is one device layer;
+    # the closures built here run under whichever scope calls them.
+    with jax.named_scope("megba.pcg_setup"):
+        num_cameras = system.Hpp.shape[0]
+        num_points = system.Hll.shape[1]
+        pd = int(round(system.Hll.shape[0] ** 0.5))
 
-    Hpp_d = damp_blocks(system.Hpp, region)
-    Hll_d = damp_rows_fm(system.Hll, region)
-    g_cam, g_pt = system.g_cam, system.g_pt
-    W = system.W
+        Hpp_d = damp_blocks(system.Hpp, region)
+        Hll_d = damp_rows_fm(system.Hll, region)
+        g_cam, g_pt = system.g_cam, system.g_pt
+        W = system.W
 
-    # Both precision rungs equilibrate + bf16-cast the stored rows; they
-    # differ only in WHERE the upcast happens (before vs after the
-    # multiply — _edge_precision).
-    equil = mixed_precision or bf16
-    d_cam = d_pt = None
-    if equil:
-        # Jacobi (scale-then-cast) equilibration: BA Jacobian columns span
-        # ~6 orders of magnitude (rotation vs focal), far beyond bf16's
-        # dynamic range.  Solve the symmetrically scaled system
-        # (D S D) x~ = D v with D = diag(H)^-1/2 — unit-diagonal, so the
-        # bf16-cast coupling operands are well-ranged — and unscale the
-        # solution at the end.
-        cd = Hpp_d.shape[-1]
-        dc = jax.lax.rsqrt(jnp.diagonal(Hpp_d, axis1=-2, axis2=-1))  # [Nc, cd]
-        Hpp_d = Hpp_d * dc[:, :, None] * dc[:, None, :]
-        d_cam = jnp.swapaxes(dc, 0, 1)  # [cd, Nc] rows
-        d_pt = jax.lax.rsqrt(jnp.stack(
-            [Hll_d[i * (pd + 1)] for i in range(pd)]))  # [pd, Np]
-        Hll_d = Hll_d * jnp.stack(
-            [d_pt[i] * d_pt[j] for i in range(pd) for j in range(pd)])
-        g_cam = g_cam * d_cam
-        g_pt = g_pt * d_pt
-        bf = jnp.bfloat16
-        if plans is not None:
-            # Sorted expansions instead of random gathers; Jp's scale
-            # rows must be in PT-slot order, like Jp itself.
-            dc_e = seg_expand(d_cam, plans.cam, plans.use_kernels)
-            dp_e_pt = seg_expand(d_pt, plans.pt, plans.use_kernels)
-            dp_e = plans.to_cam(dp_e_pt) if (
-                compute_kind == ComputeKind.EXPLICIT) else dp_e_pt
+        # Both precision rungs equilibrate + bf16-cast the stored rows; they
+        # differ only in WHERE the upcast happens (before vs after the
+        # multiply — _edge_precision).
+        equil = mixed_precision or bf16
+        d_cam = d_pt = None
+        if equil:
+            # Jacobi (scale-then-cast) equilibration: BA Jacobian columns span
+            # ~6 orders of magnitude (rotation vs focal), far beyond bf16's
+            # dynamic range.  Solve the symmetrically scaled system
+            # (D S D) x~ = D v with D = diag(H)^-1/2 — unit-diagonal, so the
+            # bf16-cast coupling operands are well-ranged — and unscale the
+            # solution at the end.
+            cd = Hpp_d.shape[-1]
+            dc = jax.lax.rsqrt(
+                jnp.diagonal(Hpp_d, axis1=-2, axis2=-1))  # [Nc, cd]
+            Hpp_d = Hpp_d * dc[:, :, None] * dc[:, None, :]
+            d_cam = jnp.swapaxes(dc, 0, 1)  # [cd, Nc] rows
+            d_pt = jax.lax.rsqrt(jnp.stack(
+                [Hll_d[i * (pd + 1)] for i in range(pd)]))  # [pd, Np]
+            Hll_d = Hll_d * jnp.stack(
+                [d_pt[i] * d_pt[j] for i in range(pd) for j in range(pd)])
+            g_cam = g_cam * d_cam
+            g_pt = g_pt * d_pt
+            bf = jnp.bfloat16
+            if plans is not None:
+                # Sorted expansions instead of random gathers; Jp's scale
+                # rows must be in PT-slot order, like Jp itself.
+                dc_e = seg_expand(d_cam, plans.cam, plans.use_kernels,
+                                  name="tile_expand_cam_to_edge")
+                dp_e_pt = seg_expand(d_pt, plans.pt, plans.use_kernels,
+                                     name="tile_expand_pt_to_edge")
+                dp_e = plans.to_cam(dp_e_pt) if (
+                    compute_kind == ComputeKind.EXPLICIT) else dp_e_pt
+            else:
+                dc_e = gather_fm(d_cam, cam_idx)  # [cd, nE]
+                dp_e = gather_fm(d_pt, pt_idx)  # [pd, nE]
+            if compute_kind == ComputeKind.EXPLICIT:
+                W = jnp.stack([
+                    W[a * pd + b] * dc_e[a] * dp_e[b]
+                    for a in range(cd) for b in range(pd)
+                ]).astype(bf)
+            else:
+                od = Jc.shape[0] // cd
+                Jc = jnp.stack([
+                    Jc[o * cd + a] * dc_e[a]
+                    for o in range(od) for a in range(cd)
+                ]).astype(bf)
+                Jp = jnp.stack([
+                    Jp[o * pd + b] * dp_e[b]
+                    for o in range(od) for b in range(pd)
+                ]).astype(bf)
+
+        Hll_inv = block_inv_fm(Hll_d)
+
+        hpl, hlp = make_coupling_matvecs(
+            W, Jc, Jp, cam_idx, pt_idx, num_cameras, num_points,
+            compute_kind, axis_name, mixed_precision=mixed_precision,
+            cam_sorted=cam_sorted, plans=plans, bf16_ops=bf16,
+            fused_kernels=fused_kernels,
+        )
+
+        if tile_plan is not None:
+            # 2-D mesh: the SINGLE matvec site becomes the fused tiled
+            # pipeline with subgroup collectives + double-buffered
+            # point-shard rotation (make_matvec_2d).  Everything OUTSIDE
+            # the PCG body — the reduced RHS, the warm-start residual
+            # priming, the back-substitution, the coarse-space builds —
+            # keeps the plain hpl/hlp products above (world psums, one per
+            # PCG solve, not per iteration), so the preconditioner family
+            # and the guards compose unchanged.
+            s_matvec = make_matvec_2d(
+                W, Jc, Jp, tile_plan, pt_idx, Hpp_d, Hll_inv,
+                num_cameras, num_points, compute_kind, axis_name,
+                mixed_precision=mixed_precision, bf16_ops=bf16,
+                bf16_collectives=bf16_collectives,
+                fused_kernels=fused_kernels)
         else:
-            dc_e = gather_fm(d_cam, cam_idx)  # [cd, nE]
-            dp_e = gather_fm(d_pt, pt_idx)  # [pd, nE]
-        if compute_kind == ComputeKind.EXPLICIT:
-            W = jnp.stack([
-                W[a * pd + b] * dc_e[a] * dp_e[b]
-                for a in range(cd) for b in range(pd)
-            ]).astype(bf)
-        else:
-            od = Jc.shape[0] // cd
-            Jc = jnp.stack([
-                Jc[o * cd + a] * dc_e[a]
-                for o in range(od) for a in range(cd)
-            ]).astype(bf)
-            Jp = jnp.stack([
-                Jp[o * pd + b] * dp_e[b]
-                for o in range(od) for b in range(pd)
-            ]).astype(bf)
+            if bf16_collectives and axis_name is not None:
+                # Compressed coupling pair for the S·p matvec ONLY: the
+                # in-body psums carry bf16 payloads while the reduced RHS /
+                # back-substitution products below keep the full-precision
+                # hpl/hlp (their psums run once per solve, not per
+                # iteration — compressing them buys nothing and costs
+                # accuracy exactly where the solution is assembled).
+                hpl_c, hlp_c = make_coupling_matvecs(
+                    W, Jc, Jp, cam_idx, pt_idx, num_cameras, num_points,
+                    compute_kind, axis_name, mixed_precision=mixed_precision,
+                    cam_sorted=cam_sorted, plans=plans, bf16_ops=bf16,
+                    bf16_collectives=True, fused_kernels=fused_kernels,
+                )
+            else:
+                hpl_c, hlp_c = hpl, hlp
 
-    Hll_inv = block_inv_fm(Hll_d)
+            def s_matvec(p: jax.Array) -> jax.Array:
+                # S p = Hpp_d p - Hpl Hll_d^-1 Hlp p     [2 psums]
+                t = block_matvec_fm(Hll_inv, hlp_c(p))
+                return cam_block_matvec(Hpp_d, p) - hpl_c(t)
 
-    hpl, hlp = make_coupling_matvecs(
-        W, Jc, Jp, cam_idx, pt_idx, num_cameras, num_points,
-        compute_kind, axis_name, mixed_precision=mixed_precision,
-        cam_sorted=cam_sorted, plans=plans, bf16_ops=bf16,
-        fused_kernels=fused_kernels,
-    )
-
-    if tile_plan is not None:
-        # 2-D mesh: the SINGLE matvec site becomes the fused tiled
-        # pipeline with subgroup collectives + double-buffered
-        # point-shard rotation (make_matvec_2d).  Everything OUTSIDE
-        # the PCG body — the reduced RHS, the warm-start residual
-        # priming, the back-substitution, the coarse-space builds —
-        # keeps the plain hpl/hlp products above (world psums, one per
-        # PCG solve, not per iteration), so the preconditioner family
-        # and the guards compose unchanged.
-        s_matvec = make_matvec_2d(
-            W, Jc, Jp, tile_plan, pt_idx, Hpp_d, Hll_inv,
-            num_cameras, num_points, compute_kind, axis_name,
-            mixed_precision=mixed_precision, bf16_ops=bf16,
-            bf16_collectives=bf16_collectives,
+        # Preconditioner operator family (solver/precond.py).  The
+        # correction/coarse rows are always accumulated in full precision
+        # (any bf16 operands are upcast inside the builds); the only
+        # precision flag threaded through is the bf16 pipeline's
+        # block-diagonal APPLY (bf16 M⁻¹ copy, f32-accumulated einsum —
+        # the coarse cycles smooth with it but assemble/solve their coarse
+        # systems in f32).  JACOBI reproduces the historical
+        # solver bitwise; `precond_fallback` is the enum-coded per-level
+        # fallback count (two-level -> block-Jacobi, SCHUR_DIAG block ->
+        # Hpp).
+        precond_apply, precond_fallback = make_schur_preconditioner(
+            precond, preconditioner, Hpp_d, Hll_inv, W, Jc, Jp,
+            cam_idx, pt_idx, num_cameras, compute_kind, axis_name,
+            cam_sorted, neumann_order=neumann_order, plans=plans,
+            cluster_plan=cluster_plan, cam_fixed=cam_fixed,
+            s_matvec=s_matvec, smooth_omega=smooth_omega, bf16=bf16,
             fused_kernels=fused_kernels)
-    else:
-        if bf16_collectives and axis_name is not None:
-            # Compressed coupling pair for the S·p matvec ONLY: the
-            # in-body psums carry bf16 payloads while the reduced RHS /
-            # back-substitution products below keep the full-precision
-            # hpl/hlp (their psums run once per solve, not per
-            # iteration — compressing them buys nothing and costs
-            # accuracy exactly where the solution is assembled).
-            hpl_c, hlp_c = make_coupling_matvecs(
-                W, Jc, Jp, cam_idx, pt_idx, num_cameras, num_points,
-                compute_kind, axis_name, mixed_precision=mixed_precision,
-                cam_sorted=cam_sorted, plans=plans, bf16_ops=bf16,
-                bf16_collectives=True, fused_kernels=fused_kernels,
-            )
-        else:
-            hpl_c, hlp_c = hpl, hlp
 
-        def s_matvec(p: jax.Array) -> jax.Array:
-            # S p = Hpp_d p - Hpl Hll_d^-1 Hlp p     [2 psums]
-            t = block_matvec_fm(Hll_inv, hlp_c(p))
-            return cam_block_matvec(Hpp_d, p) - hpl_c(t)
+        # Reduced RHS v = g_cam - Hpl Hll^-1 g_pt    [1 psum]
+        v = g_cam - hpl(block_matvec_fm(Hll_inv, g_pt))
 
-    # Preconditioner operator family (solver/precond.py).  The
-    # correction/coarse rows are always accumulated in full precision
-    # (any bf16 operands are upcast inside the builds); the only
-    # precision flag threaded through is the bf16 pipeline's
-    # block-diagonal APPLY (bf16 M⁻¹ copy, f32-accumulated einsum —
-    # the coarse cycles smooth with it but assemble/solve their coarse
-    # systems in f32).  JACOBI reproduces the historical
-    # solver bitwise; `precond_fallback` is the enum-coded per-level
-    # fallback count (two-level -> block-Jacobi, SCHUR_DIAG block ->
-    # Hpp).
-    precond_apply, precond_fallback = make_schur_preconditioner(
-        precond, preconditioner, Hpp_d, Hll_inv, W, Jc, Jp,
-        cam_idx, pt_idx, num_cameras, compute_kind, axis_name,
-        cam_sorted, neumann_order=neumann_order, plans=plans,
-        cluster_plan=cluster_plan, cam_fixed=cam_fixed,
-        s_matvec=s_matvec, smooth_omega=smooth_omega, bf16=bf16,
-        fused_kernels=fused_kernels)
+        if x0 is not None and equil:
+            # The CG runs in the symmetrically scaled variables x~ = x / d;
+            # bring the (original-variable) warm start over.
+            x0 = x0 / d_cam
 
-    # Reduced RHS v = g_cam - Hpl Hll^-1 g_pt    [1 psum]
-    v = g_cam - hpl(block_matvec_fm(Hll_inv, g_pt))
-
-    if x0 is not None and equil:
-        # The CG runs in the symmetrically scaled variables x~ = x / d;
-        # bring the (original-variable) warm start over.
-        x0 = x0 / d_cam
-
-    if bf16 and tol_relative:
-        # Attainable-accuracy floor: a bf16-operand operator cannot
-        # resolve relative preconditioned-residual energies below
-        # ~eps_bf16² — an Eisenstat-Walker eta driven under the floor
-        # (eta_min defaults to 1e-6) would spin the inner solve at its
-        # noise floor for the full budget.  Clamp the RELATIVE
-        # threshold only; an absolute `tol` has no scale to clamp
-        # against (the refuse guard handles stagnation there).
-        tol = jnp.maximum(jnp.asarray(tol, v.dtype),
-                          jnp.asarray(_BF16_TOL_FLOOR, v.dtype))
+        if bf16 and tol_relative:
+            # Attainable-accuracy floor: a bf16-operand operator cannot
+            # resolve relative preconditioned-residual energies below
+            # ~eps_bf16² — an Eisenstat-Walker eta driven under the floor
+            # (eta_min defaults to 1e-6) would spin the inner solve at its
+            # noise floor for the full budget.  Clamp the RELATIVE
+            # threshold only; an absolute `tol` has no scale to clamp
+            # against (the refuse guard handles stagnation there).
+            tol = jnp.maximum(jnp.asarray(tol, v.dtype),
+                              jnp.asarray(_BF16_TOL_FLOOR, v.dtype))
 
     x, k, rho, r0_ratio, restarts, broken = _pcg_core(
         s_matvec, precond_apply, v,
@@ -1338,10 +1361,11 @@ def schur_pcg_solve(
         guard=guard, max_restarts=max_restarts, fused=not bf16)
 
     # Back-substitute the point update       [1 psum]
-    dx_pt = block_matvec_fm(Hll_inv, g_pt - hlp(x))
-    if equil:
-        x = x * d_cam  # unscale back to the original variables
-        dx_pt = dx_pt * d_pt
+    with jax.named_scope("megba.pcg_backsub"):
+        dx_pt = block_matvec_fm(Hll_inv, g_pt - hlp(x))
+        if equil:
+            x = x * d_cam  # unscale back to the original variables
+            dx_pt = dx_pt * d_pt
     return PCGResult(dx_cam=x, dx_pt=dx_pt, iterations=k, rho=rho,
                      r0_ratio=r0_ratio, breakdowns=restarts, broken=broken,
                      precond_fallback=precond_fallback)

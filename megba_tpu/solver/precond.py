@@ -944,7 +944,8 @@ def make_schur_preconditioner(
 
         def base_apply(r):
             return _fused.fused_block_diag_apply(
-                Hrows, r, bf16_operands=bf16, interpret=_interp)
+                Hrows, r, bf16_operands=bf16, interpret=_interp,
+                name="fused_block_diag_cam")
     elif bf16:
         Minv_bf16 = Minv.astype(jnp.bfloat16)
 

@@ -62,6 +62,19 @@ class _Phase:
         return x
 
 
+def is_subphase(name: str) -> bool:
+    """A phase named `<parent>.<child>` is timed inside its parent's
+    block: reported under its own name, never added into a total."""
+    return "." in name
+
+
+def top_level_seconds(phases: Dict[str, Dict[str, float]]) -> float:
+    """Summed `total_s` of a `PhaseTimer.as_dict()` payload, sub-phases
+    left out (their time is already inside their parent's)."""
+    return sum(ph.get("total_s", 0.0) for name, ph in phases.items()
+               if not is_subphase(name))
+
+
 class PhaseTimer:
     """Accumulates wall-clock per named phase; device-sync aware.
 
@@ -70,6 +83,11 @@ class PhaseTimer:
 
         with timer.phase("pcg") as ph:
             out = ph.sync(pcg_solve(...))
+
+    A sub-phase is named `<parent>.<child>` and timed inside the
+    parent's block (`with timer.phase("plan.key"):` inside
+    `with timer.phase("plan"):`); `report()` and `top_level_seconds`
+    count only the parents.
     """
 
     def __init__(self):
@@ -124,9 +142,9 @@ class PhaseTimer:
         for name in sorted(self.totals, key=self.totals.get, reverse=True):
             t, c = self.totals[name], self.counts[name]
             lines.append(f"{name}: {t * 1e3:.1f} ms total / {c} calls = {t / c * 1e3:.2f} ms")
-        total = sum(self.totals.values())
-        lines.append(
-            f"total: {total * 1e3:.1f} ms over {len(self.totals)} phases")
+        top = [n for n in self.totals if not is_subphase(n)]
+        total = sum(self.totals[n] for n in top)
+        lines.append(f"total: {total * 1e3:.1f} ms over {len(top)} phases")
         return "\n".join(lines)
 
 
