@@ -110,6 +110,9 @@ def format_report(rep: SolveReport, index: int = 0) -> str:
             line += f" reuse_factor={rf:.1f}"
         if occ is not None:
             line += f" occupancy={occ:.3f}"
+        if "window_pt" in tiles:
+            line += (f" window_pt={tiles['window_pt']}"
+                     f" ({tiles['window_share_pt']:.4f} of its block)")
         lines.append(line)
         for dname in ("fused_to_pt", "fused_to_cam"):
             fp = tiles.get(dname)

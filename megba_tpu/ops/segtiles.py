@@ -25,6 +25,22 @@ matmuls that ride the MXU, organised by a host-side *plan*:
   3. `tile_expand`: the transpose — `table[F, B] @ onehot[B, T]` —
      replaces `jnp.take(table, idx, axis=1)` (segment -> edge gather).
 
+The one-hot spans a tile's *window*, not its whole block: a tile's slots
+hold few consecutive segment ids when each segment has several edges,
+so each tile gets `tile_lo` (its smallest local id rounded down to a
+lane multiple of 128, clamped so the window ends inside the block) and
+the plan one static `window` (the narrowest lane multiple covering every
+tile from its `tile_lo`), both read off the plan's own index stream
+(`tile_windows`).  The kernels then build `onehot[window, T]` from
+`local - tile_lo` and add (reduce) or read (expand) the block at lane
+offset `tile_lo`; the skipped one-hot entries are exact zeros.  The
+plan's sparsest tile sets the window of all its tiles.  On the venice
+benchmark graph (5-6 edges a point, B 2048, T 1024) the 4,899 point
+tiles span 206 ids each and the window is 384, a fifth of the block;
+when every segment has at least 2 edges a tile spans at most T/2 + 1
+ids, so the window is at most 640.  Camera blocks (B 128) and plans whose
+tiles span their block take `window == block` and `tile_lo` 0.
+
 Everything is feature-major ([F, N] rows, see core/fm.py).  A pure-XLA
 fallback with identical semantics (`reduce_fallback` / `expand_fallback`)
 runs the same plan on CPU / in tests and under the sharded mesh path.
@@ -55,6 +71,7 @@ DEFAULT_TILE_CAM = 2048
 DEFAULT_BLOCK_CAM = 128
 DEFAULT_TILE_PT = 1024
 DEFAULT_BLOCK_PT = 2048
+LANES = 128  # vreg lane width: one-hot windows start and end on it
 
 
 def _fit_tile(t: int, n: int) -> int:
@@ -84,6 +101,8 @@ class TilePlan:
     mask: np.ndarray  # [n_slots] float32 1.0 real / 0.0 padding
     tile_block: np.ndarray  # [n_tiles] int32 block index per tile
     tile_first: np.ndarray  # [n_tiles] int32 1 if first tile of its block
+    tile_lo: np.ndarray  # [n_tiles] int32 first local id of the tile's window
+    window: int  # one-hot width: every slot's local - tile_lo in [0, window)
 
     @property
     def n_slots(self) -> int:
@@ -155,6 +174,7 @@ def build_tile_plan(
     seg[slot_of_edge] = seg_sorted
     mask[slot_of_edge] = 1.0
     local = seg - np.repeat(tile_block, tile).astype(np.int64) * block
+    tile_lo, window = tile_windows(local.reshape(n_tiles, tile), block)
     return TilePlan(
         tile=tile,
         block=block,
@@ -167,7 +187,32 @@ def build_tile_plan(
         mask=mask,
         tile_block=tile_block,
         tile_first=tile_first,
+        tile_lo=tile_lo,
+        window=window,
     )
+
+
+def tile_windows(local_by_tile: np.ndarray, block: int,
+                 window: int = 0) -> Tuple[np.ndarray, int]:
+    """Each tile's one-hot window within its block: (tile_lo, window).
+
+    A tile's slots (padding included) span few consecutive local ids
+    when its segments have several edges each — 206 at venice's points,
+    of a 2,048-wide block.  `tile_lo` is a tile's smallest local id
+    rounded down to a lane multiple (128), `window` the narrowest lane
+    multiple that covers every tile from its `tile_lo` (at least the
+    `window` given), and `tile_lo` is clamped so that the window ends
+    inside the block.  A block that is not a lane multiple, or tiles
+    that need the whole block, keep `window == block` and `tile_lo` 0:
+    the kernels then build the whole block's one-hot.
+    """
+    n_tiles = local_by_tile.shape[0]
+    lo = local_by_tile.min(axis=1) // LANES * LANES
+    need = int((local_by_tile.max(axis=1) - lo).max(initial=0)) + 1
+    window = max(window, -(-need // LANES) * LANES)
+    if block % LANES or window >= block:
+        return np.zeros(n_tiles, np.int32), block
+    return np.minimum(lo, block - window).astype(np.int32), window
 
 
 @dataclasses.dataclass(frozen=True)
@@ -184,9 +229,11 @@ class DevicePlan:
     block: int
     num_segments: int
     num_blocks: int
+    window: int  # one-hot width (== block: the whole block's one-hot)
     local: jax.Array  # [1, n_slots] int32 (2-D for Mosaic block specs)
     tile_block: jax.Array  # [n_tiles] int32
     tile_first: jax.Array  # [n_tiles] int32
+    tile_lo: jax.Array  # [n_tiles] int32 window start (TilePlan.tile_lo)
     mask: jax.Array  # [n_slots] f32
     perm: jax.Array  # [n_slots] int32
     inv: Optional[jax.Array]  # [n_other] int32: slot in THIS plan holding
@@ -200,9 +247,11 @@ def device_plan(plan: TilePlan, inv: Optional[np.ndarray] = None) -> DevicePlan:
         block=plan.block,
         num_segments=plan.num_segments,
         num_blocks=plan.num_blocks,
+        window=plan.window,
         local=np.asarray(plan.local)[None, :],
         tile_block=np.asarray(plan.tile_block),
         tile_first=np.asarray(plan.tile_first),
+        tile_lo=np.asarray(plan.tile_lo),
         mask=np.asarray(plan.mask),
         perm=np.asarray(plan.perm),
         inv=None if inv is None else np.asarray(inv),
@@ -211,8 +260,9 @@ def device_plan(plan: TilePlan, inv: Optional[np.ndarray] = None) -> DevicePlan:
 
 jax.tree_util.register_dataclass(
     DevicePlan,
-    data_fields=["local", "tile_block", "tile_first", "mask", "perm", "inv"],
-    meta_fields=["tile", "block", "num_segments", "num_blocks"],
+    data_fields=["local", "tile_block", "tile_first", "tile_lo", "mask",
+                 "perm", "inv"],
+    meta_fields=["tile", "block", "num_segments", "num_blocks", "window"],
 )
 
 
@@ -243,57 +293,71 @@ def cross_perm(primary: TilePlan, secondary: TilePlan) -> np.ndarray:
 # kernel can be followed across program edits, which renumber the HLO.
 
 
-def _reduce_kernel(tb_ref, tf_ref, local_ref, data_ref, out_ref, *, block):
-    """Accumulate one tile's [F, T] rows into its block's [F, B] sums."""
-    i = pl.program_id(0)
-    tile = local_ref.shape[1]
+def _tile_onehot(local_ref, lo_ref, window):
+    """(one-hot [window, T] of this tile's local ids counted from its
+    window start, that start): the start is the tile's `tile_lo`, a lane
+    multiple as the plan makes it (0 where the window is the block)."""
+    lo = pl.multiple_of(lo_ref[pl.program_id(0)], LANES)
     onehot = (
-        local_ref[:, :] == jax.lax.broadcasted_iota(
-            jnp.int32, (block, tile), 0)
-    ).astype(jnp.float32)  # [B, T]
+        local_ref[:, :] - lo == jax.lax.broadcasted_iota(
+            jnp.int32, (window, local_ref.shape[1]), 0)
+    ).astype(jnp.float32)
+    return onehot, lo
+
+
+def _accumulate(out_ref, partial, tf_ref, lo):
+    """Add one tile's [F, window] partial sums to its block's resident
+    [F, block] output at lane offset `lo`; the block's first tile zeroes
+    the block first."""
+    @pl.when(tf_ref[pl.program_id(0)] == 1)
+    def _zero():
+        out_ref[:, :] = jnp.zeros(out_ref.shape, out_ref.dtype)
+
+    win = pl.ds(lo, partial.shape[1])
+    out_ref[:, win] = (out_ref[:, win] + partial).astype(out_ref.dtype)
+
+
+def _reduce_kernel(tb_ref, tf_ref, lo_ref, local_ref, data_ref, out_ref, *,
+                   window):
+    """Accumulate one tile's [F, T] rows into its block's [F, B] sums."""
+    onehot, lo = _tile_onehot(local_ref, lo_ref, window)
     partial = jax.lax.dot_general(
         data_ref[:, :].astype(jnp.float32), onehot,
         (((1,), (1,)), ((), ())),
         precision=fused_ops.ONEHOT_PRECISION,
         preferred_element_type=jnp.float32,
-    )  # [F, B]
-
-    @pl.when(tf_ref[i] == 1)
-    def _init():
-        out_ref[:, :] = partial.astype(out_ref.dtype)
-
-    @pl.when(tf_ref[i] == 0)
-    def _acc():
-        out_ref[:, :] = (out_ref[:, :] + partial).astype(out_ref.dtype)
+    )  # [F, window]
+    _accumulate(out_ref, partial, tf_ref, lo)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("tile", "block", "num_blocks", "interpret", "name"))
+    static_argnames=("tile", "block", "num_blocks", "window", "interpret",
+                     "name"))
 def _tile_reduce_call(
-    data, local, tile_block, tile_first, *, tile, block, num_blocks,
-    interpret, name="tile_reduce",
+    data, local, tile_block, tile_first, tile_lo, *, tile, block, num_blocks,
+    window, interpret, name="tile_reduce",
 ):
     F = data.shape[0]
     n_tiles = tile_block.shape[0]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # tile_block, tile_first
+        num_scalar_prefetch=3,  # tile_block, tile_first, tile_lo
         grid=(n_tiles,),
         in_specs=[
-            pl.BlockSpec((1, tile), lambda i, tb, tf: (0, i)),
-            pl.BlockSpec((F, tile), lambda i, tb, tf: (0, i)),
+            pl.BlockSpec((1, tile), lambda i, tb, tf, lo: (0, i)),
+            pl.BlockSpec((F, tile), lambda i, tb, tf, lo: (0, i)),
         ],
         out_specs=pl.BlockSpec(
-            (F, block), lambda i, tb, tf: (0, tb[i])),
+            (F, block), lambda i, tb, tf, lo: (0, tb[i])),
     )
     return pl.pallas_call(
-        functools.partial(_reduce_kernel, block=block),
+        functools.partial(_reduce_kernel, window=window),
         grid_spec=grid_spec,
         out_shape=fused_ops.kernel_out_shape(
             (F, num_blocks * block), jnp.float32, local, data),
         interpret=interpret,
         name=name,
-    )(tile_block, tile_first, local, data)
+    )(tile_block, tile_first, tile_lo, local, data)
 
 
 def tile_reduce(
@@ -308,21 +372,17 @@ def tile_reduce(
     if unsure).
     """
     out = _tile_reduce_call(
-        data, plan.local, plan.tile_block, plan.tile_first,
+        data, plan.local, plan.tile_block, plan.tile_first, plan.tile_lo,
         tile=plan.tile, block=plan.block, num_blocks=plan.num_blocks,
-        interpret=interpret, name=name,
+        window=plan.window, interpret=interpret, name=name,
     )
     return out[:, : plan.num_segments].astype(data.dtype)
 
 
-def _expand_kernel(tb_ref, local_ref, table_ref, out_ref, *, block):
-    tile = local_ref.shape[1]
-    onehot = (
-        local_ref[:, :] == jax.lax.broadcasted_iota(
-            jnp.int32, (block, tile), 0)
-    ).astype(jnp.float32)  # [B, T]
+def _expand_kernel(tb_ref, lo_ref, local_ref, table_ref, out_ref, *, window):
+    onehot, lo = _tile_onehot(local_ref, lo_ref, window)
     out_ref[:, :] = jax.lax.dot_general(
-        table_ref[:, :].astype(jnp.float32), onehot,
+        table_ref[:, pl.ds(lo, window)].astype(jnp.float32), onehot,
         (((1,), (0,)), ((), ())),
         precision=fused_ops.ONEHOT_PRECISION,
         preferred_element_type=jnp.float32,
@@ -331,32 +391,33 @@ def _expand_kernel(tb_ref, local_ref, table_ref, out_ref, *, block):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("tile", "block", "num_blocks", "interpret", "name"))
+    static_argnames=("tile", "block", "num_blocks", "window", "interpret",
+                     "name"))
 def _tile_expand_call(
-    table, local, tile_block, *, tile, block, num_blocks, interpret,
-    name="tile_expand",
+    table, local, tile_block, tile_lo, *, tile, block, num_blocks, window,
+    interpret, name="tile_expand",
 ):
     F = table.shape[0]
     n_tiles = tile_block.shape[0]
     pad = num_blocks * block - table.shape[1]
     table_p = jnp.pad(table, ((0, 0), (0, pad))) if pad else table
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,  # tile_block
+        num_scalar_prefetch=2,  # tile_block, tile_lo
         grid=(n_tiles,),
         in_specs=[
-            pl.BlockSpec((1, tile), lambda i, tb: (0, i)),
-            pl.BlockSpec((F, block), lambda i, tb: (0, tb[i])),
+            pl.BlockSpec((1, tile), lambda i, tb, lo: (0, i)),
+            pl.BlockSpec((F, block), lambda i, tb, lo: (0, tb[i])),
         ],
-        out_specs=pl.BlockSpec((F, tile), lambda i, tb: (0, i)),
+        out_specs=pl.BlockSpec((F, tile), lambda i, tb, lo: (0, i)),
     )
     return pl.pallas_call(
-        functools.partial(_expand_kernel, block=block),
+        functools.partial(_expand_kernel, window=window),
         grid_spec=grid_spec,
         out_shape=fused_ops.kernel_out_shape(
             (F, n_tiles * tile), table.dtype, local, table_p),
         interpret=interpret,
         name=name,
-    )(tile_block, local, table_p)
+    )(tile_block, tile_lo, local, table_p)
 
 
 def tile_expand(
@@ -369,9 +430,9 @@ def tile_expand(
     their block's running-max real segment; mask before reducing).
     """
     return _tile_expand_call(
-        table, plan.local, plan.tile_block,
+        table, plan.local, plan.tile_block, plan.tile_lo,
         tile=plan.tile, block=plan.block, num_blocks=plan.num_blocks,
-        interpret=interpret, name=name,
+        window=plan.window, interpret=interpret, name=name,
     )
 
 
@@ -421,8 +482,8 @@ def seg_expand(
 # ---------------------------------------------------------------------------
 
 
-def _jtj_kernel(tb_ref, tf_ref, local_ref, j_ref, r_ref, out_ref,
-                *, block, d, od):
+def _jtj_kernel(tb_ref, tf_ref, lo_ref, local_ref, j_ref, r_ref, out_ref,
+                *, window, d, od):
     """One tile: rows of J^T J (d*d) and -J^T r (d) reduced to its block.
 
     The per-edge outer-product rows are built in VMEM from the [od*d, T]
@@ -431,8 +492,6 @@ def _jtj_kernel(tb_ref, tf_ref, local_ref, j_ref, r_ref, out_ref,
     fuses the same way with shared-memory staging + atomicAdd,
     build_linear_system.cu:88-146).
     """
-    i = pl.program_id(0)
-    tile = local_ref.shape[1]
     rows = []
     for a in range(d):
         for b in range(d):
@@ -448,54 +507,44 @@ def _jtj_kernel(tb_ref, tf_ref, local_ref, j_ref, r_ref, out_ref,
             acc = t if acc is None else acc + t
         rows.append(-acc[None, :])
     feat = jnp.concatenate(rows, axis=0).astype(jnp.float32)  # [d*d+d, T]
-    onehot = (
-        local_ref[:, :] == jax.lax.broadcasted_iota(
-            jnp.int32, (block, tile), 0)
-    ).astype(jnp.float32)
+    onehot, lo = _tile_onehot(local_ref, lo_ref, window)
     partial = jax.lax.dot_general(
         feat, onehot, (((1,), (1,)), ((), ())),
         precision=fused_ops.ONEHOT_PRECISION,
         preferred_element_type=jnp.float32,
-    )  # [d*d+d, B]
-
-    @pl.when(tf_ref[i] == 1)
-    def _init():
-        out_ref[:, :] = partial.astype(out_ref.dtype)
-
-    @pl.when(tf_ref[i] == 0)
-    def _acc():
-        out_ref[:, :] = (out_ref[:, :] + partial).astype(out_ref.dtype)
+    )  # [d*d+d, window]
+    _accumulate(out_ref, partial, tf_ref, lo)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("d", "od", "tile", "block", "num_blocks", "interpret",
-                     "name"))
+    static_argnames=("d", "od", "tile", "block", "num_blocks", "window",
+                     "interpret", "name"))
 def _jtj_reduce_call(
-    J, r, local, tile_block, tile_first, *, d, od, tile, block, num_blocks,
-    interpret, name="jtj_grad_reduce",
+    J, r, local, tile_block, tile_first, tile_lo, *, d, od, tile, block,
+    num_blocks, window, interpret, name="jtj_grad_reduce",
 ):
     n_tiles = tile_block.shape[0]
     feat = d * d + d
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,  # tile_block, tile_first, tile_lo
         grid=(n_tiles,),
         in_specs=[
-            pl.BlockSpec((1, tile), lambda i, tb, tf: (0, i)),
-            pl.BlockSpec((od * d, tile), lambda i, tb, tf: (0, i)),
-            pl.BlockSpec((od, tile), lambda i, tb, tf: (0, i)),
+            pl.BlockSpec((1, tile), lambda i, tb, tf, lo: (0, i)),
+            pl.BlockSpec((od * d, tile), lambda i, tb, tf, lo: (0, i)),
+            pl.BlockSpec((od, tile), lambda i, tb, tf, lo: (0, i)),
         ],
         out_specs=pl.BlockSpec(
-            (feat, block), lambda i, tb, tf: (0, tb[i])),
+            (feat, block), lambda i, tb, tf, lo: (0, tb[i])),
     )
     return pl.pallas_call(
-        functools.partial(_jtj_kernel, block=block, d=d, od=od),
+        functools.partial(_jtj_kernel, window=window, d=d, od=od),
         grid_spec=grid_spec,
         out_shape=fused_ops.kernel_out_shape(
             (feat, num_blocks * block), jnp.float32, local, J, r),
         interpret=interpret,
         name=name,
-    )(tile_block, tile_first, local, J, r)
+    )(tile_block, tile_first, tile_lo, local, J, r)
 
 
 def jtj_grad_reduce(
@@ -516,9 +565,10 @@ def jtj_grad_reduce(
     d = J.shape[0] // od
     if use_kernels or interpret:
         out = _jtj_reduce_call(
-            J, r, plan.local, plan.tile_block, plan.tile_first,
+            J, r, plan.local, plan.tile_block, plan.tile_first, plan.tile_lo,
             d=d, od=od, tile=plan.tile, block=plan.block,
-            num_blocks=plan.num_blocks, interpret=interpret, name=name)
+            num_blocks=plan.num_blocks, window=plan.window,
+            interpret=interpret, name=name)
         out = out[:, : plan.num_segments].astype(J.dtype)
     else:
         out = _jtj_fallback_chunked(J, r, plan, d, od)
@@ -598,22 +648,18 @@ def _jtj_fallback_chunked(J, r, plan: DevicePlan, d: int, od: int,
 # ---------------------------------------------------------------------------
 
 
-def _expand_matvec_kernel(tb_ref, local_ref, j_ref, table_ref, out_ref,
-                          *, block, d):
+def _expand_matvec_kernel(tb_ref, lo_ref, local_ref, j_ref, table_ref,
+                          out_ref, *, window, d):
     """u[o] = sum_a J[o*d+a] * table[a, seg]: gather + per-edge matvec.
 
     The vertex table block lives entirely in VMEM; the gather is the
     one-hot matmul, the [od, T] product rows are the only HBM write —
     the [d, T] expanded rows never exist outside VMEM.
     """
-    tile = local_ref.shape[1]
     od = j_ref.shape[0] // d
-    onehot = (
-        local_ref[:, :] == jax.lax.broadcasted_iota(
-            jnp.int32, (block, tile), 0)
-    ).astype(jnp.float32)  # [B, T]
+    onehot, lo = _tile_onehot(local_ref, lo_ref, window)
     pe = jax.lax.dot_general(
-        table_ref[:, :].astype(jnp.float32), onehot,
+        table_ref[:, pl.ds(lo, window)].astype(jnp.float32), onehot,
         (((1,), (0,)), ((), ())),
         precision=fused_ops.ONEHOT_PRECISION,
         preferred_element_type=jnp.float32,
@@ -628,44 +674,43 @@ def _expand_matvec_kernel(tb_ref, local_ref, j_ref, table_ref, out_ref,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("d", "tile", "block", "num_blocks", "interpret", "name"))
+    static_argnames=("d", "tile", "block", "num_blocks", "window",
+                     "interpret", "name"))
 def _expand_matvec_call(
-    J, table, local, tile_block, *, d, tile, block, num_blocks, interpret,
-    name="coupling_expand",
+    J, table, local, tile_block, tile_lo, *, d, tile, block, num_blocks,
+    window, interpret, name="coupling_expand",
 ):
     od = J.shape[0] // d
     n_tiles = tile_block.shape[0]
     pad = num_blocks * block - table.shape[1]
     table_p = jnp.pad(table, ((0, 0), (0, pad))) if pad else table
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,  # tile_block, tile_lo
         grid=(n_tiles,),
         in_specs=[
-            pl.BlockSpec((1, tile), lambda i, tb: (0, i)),
-            pl.BlockSpec((J.shape[0], tile), lambda i, tb: (0, i)),
-            pl.BlockSpec((d, block), lambda i, tb: (0, tb[i])),
+            pl.BlockSpec((1, tile), lambda i, tb, lo: (0, i)),
+            pl.BlockSpec((J.shape[0], tile), lambda i, tb, lo: (0, i)),
+            pl.BlockSpec((d, block), lambda i, tb, lo: (0, tb[i])),
         ],
-        out_specs=pl.BlockSpec((od, tile), lambda i, tb: (0, i)),
+        out_specs=pl.BlockSpec((od, tile), lambda i, tb, lo: (0, i)),
     )
     return pl.pallas_call(
-        functools.partial(_expand_matvec_kernel, block=block, d=d),
+        functools.partial(_expand_matvec_kernel, window=window, d=d),
         grid_spec=grid_spec,
         out_shape=fused_ops.kernel_out_shape(
             (od, n_tiles * tile), jnp.float32, local, J, table_p),
         interpret=interpret,
         name=name,
-    )(tile_block, local, J, table_p)
+    )(tile_block, tile_lo, local, J, table_p)
 
 
-def _matvec_reduce_kernel(tb_ref, tf_ref, local_ref, j_ref, u_ref, out_ref,
-                          *, block, d):
+def _matvec_reduce_kernel(tb_ref, tf_ref, lo_ref, local_ref, j_ref, u_ref,
+                          out_ref, *, window, d):
     """out[b, seg] += sum_o J[o*d+b] * u[o]: per-edge J^T u + reduce.
 
     The [d, T] product rows are formed in VMEM and immediately
     contracted onto the block axis — they never touch HBM.
     """
-    i = pl.program_id(0)
-    tile = local_ref.shape[1]
     od = u_ref.shape[0]
     rows = []
     for b in range(d):
@@ -676,52 +721,43 @@ def _matvec_reduce_kernel(tb_ref, tf_ref, local_ref, j_ref, u_ref, out_ref,
             acc = t if acc is None else acc + t
         rows.append(acc[None, :])
     te = jnp.concatenate(rows, axis=0)  # [d, T]
-    onehot = (
-        local_ref[:, :] == jax.lax.broadcasted_iota(
-            jnp.int32, (block, tile), 0)
-    ).astype(jnp.float32)
+    onehot, lo = _tile_onehot(local_ref, lo_ref, window)
     partial = jax.lax.dot_general(
         te, onehot, (((1,), (1,)), ((), ())),
         precision=fused_ops.ONEHOT_PRECISION,
         preferred_element_type=jnp.float32,
-    )  # [d, B]
-
-    @pl.when(tf_ref[i] == 1)
-    def _init():
-        out_ref[:, :] = partial.astype(out_ref.dtype)
-
-    @pl.when(tf_ref[i] == 0)
-    def _acc():
-        out_ref[:, :] = (out_ref[:, :] + partial).astype(out_ref.dtype)
+    )  # [d, window]
+    _accumulate(out_ref, partial, tf_ref, lo)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("d", "tile", "block", "num_blocks", "interpret", "name"))
+    static_argnames=("d", "tile", "block", "num_blocks", "window",
+                     "interpret", "name"))
 def _matvec_reduce_call(
-    J, u, local, tile_block, tile_first, *, d, tile, block, num_blocks,
-    interpret, name="coupling_reduce",
+    J, u, local, tile_block, tile_first, tile_lo, *, d, tile, block,
+    num_blocks, window, interpret, name="coupling_reduce",
 ):
     n_tiles = tile_block.shape[0]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,  # tile_block, tile_first, tile_lo
         grid=(n_tiles,),
         in_specs=[
-            pl.BlockSpec((1, tile), lambda i, tb, tf: (0, i)),
-            pl.BlockSpec((J.shape[0], tile), lambda i, tb, tf: (0, i)),
-            pl.BlockSpec((u.shape[0], tile), lambda i, tb, tf: (0, i)),
+            pl.BlockSpec((1, tile), lambda i, tb, tf, lo: (0, i)),
+            pl.BlockSpec((J.shape[0], tile), lambda i, tb, tf, lo: (0, i)),
+            pl.BlockSpec((u.shape[0], tile), lambda i, tb, tf, lo: (0, i)),
         ],
         out_specs=pl.BlockSpec(
-            (d, block), lambda i, tb, tf: (0, tb[i])),
+            (d, block), lambda i, tb, tf, lo: (0, tb[i])),
     )
     return pl.pallas_call(
-        functools.partial(_matvec_reduce_kernel, block=block, d=d),
+        functools.partial(_matvec_reduce_kernel, window=window, d=d),
         grid_spec=grid_spec,
         out_shape=fused_ops.kernel_out_shape(
             (d, num_blocks * block), jnp.float32, local, J, u),
         interpret=interpret,
         name=name,
-    )(tile_block, tile_first, local, J, u)
+    )(tile_block, tile_first, tile_lo, local, J, u)
 
 
 def coupling_expand(
@@ -741,8 +777,9 @@ def coupling_expand(
     if use_kernels or interpret:
         return _expand_matvec_call(
             J, table.astype(jnp.float32), plan.local, plan.tile_block,
-            d=d, tile=plan.tile, block=plan.block,
-            num_blocks=plan.num_blocks, interpret=interpret, name=name)
+            plan.tile_lo, d=d, tile=plan.tile, block=plan.block,
+            num_blocks=plan.num_blocks, window=plan.window,
+            interpret=interpret, name=name)
     od = J.shape[0] // d
     pe = expand_fallback(table, plan)
     return jnp.stack([
@@ -766,9 +803,10 @@ def coupling_reduce(
     """
     if use_kernels or interpret:
         out = _matvec_reduce_call(
-            J, u, plan.local, plan.tile_block, plan.tile_first,
+            J, u, plan.local, plan.tile_block, plan.tile_first, plan.tile_lo,
             d=d, tile=plan.tile, block=plan.block,
-            num_blocks=plan.num_blocks, interpret=interpret, name=name)
+            num_blocks=plan.num_blocks, window=plan.window,
+            interpret=interpret, name=name)
         return out[:, : plan.num_segments]
     od = u.shape[0]
     te = jnp.stack([
@@ -907,18 +945,27 @@ def _pad_device_plan(dp: DevicePlan, n_tiles_to: int, junk_block: bool):
             [dp.tile_block, np.full((add,), nb - 1, np.int32)])
         tf = np.concatenate(
             [dp.tile_first, np.asarray([1] + [0] * (add - 1), np.int32)])
+        lo = np.concatenate([dp.tile_lo, np.zeros((add,), np.int32)])
         mask = np.concatenate([dp.mask, np.zeros((pad,), dp.mask.dtype)])
         perm = np.concatenate([dp.perm, np.zeros((pad,), np.int32)])
         inv = dp.inv
         if inv is not None:
             inv = np.concatenate([inv, np.zeros((pad,), np.int32)])
     else:
-        local, tb, tf, mask, perm, inv = (
-            dp.local, dp.tile_block, dp.tile_first, dp.mask, dp.perm,
-            dp.inv)
+        local, tb, tf, lo, mask, perm, inv = (
+            dp.local, dp.tile_block, dp.tile_first, dp.tile_lo, dp.mask,
+            dp.perm, dp.inv)
     return dataclasses.replace(
         dp, num_blocks=nb, local=local, tile_block=tb, tile_first=tf,
-        mask=mask, perm=perm, inv=inv)
+        tile_lo=lo, mask=mask, perm=perm, inv=inv)
+
+
+def _widen_window(dp: DevicePlan, window: int) -> DevicePlan:
+    """`dp` with one-hot windows at least `window` wide (host numpy):
+    stacked shards share one static window, the widest shard's."""
+    lo, window = tile_windows(
+        np.asarray(dp.local).reshape(-1, dp.tile), dp.block, window)
+    return dataclasses.replace(dp, tile_lo=lo, window=window)
 
 
 def make_sharded_dual_plans(
@@ -954,7 +1001,9 @@ def make_sharded_dual_plans(
     segments (so outputs align for the psum); both plan kinds are padded
     to the max per-shard tile count with junk-block tiles, and tile
     sizes are fitted ONCE from the largest shard so every shard's plan
-    leaves share one tile shape (stacking would fail otherwise).
+    leaves share one tile shape (stacking would fail otherwise); each
+    plan kind takes its widest shard's one-hot window, a static shape
+    too.
     """
     cam_idx = np.asarray(cam_idx)
     pt_idx = np.asarray(pt_idx)
@@ -978,11 +1027,15 @@ def make_sharded_dual_plans(
 
     max_tc = max(int(dp.cam.tile_block.shape[0]) for dp, _ in plans)
     max_tp = max(int(dp.pt.tile_block.shape[0]) for dp, _ in plans)
+    max_wc = max(dp.cam.window for dp, _ in plans)
+    max_wp = max(dp.pt.window for dp, _ in plans)
     stacked_c, stacked_p, perms = [], [], []
     for dp, cam_perm in plans:
         slots_before = int(dp.cam.mask.shape[0])
-        c = _pad_device_plan(dp.cam, max_tc, junk_block=True)
-        p = _pad_device_plan(dp.pt, max_tp, junk_block=True)
+        c = _pad_device_plan(
+            _widen_window(dp.cam, max_wc), max_tc, junk_block=True)
+        p = _pad_device_plan(
+            _widen_window(dp.pt, max_wp), max_tp, junk_block=True)
         pad_slots = int(c.mask.shape[0]) - slots_before
         if pad_slots:
             cam_perm = np.concatenate(
@@ -1979,19 +2032,29 @@ def check_kernels(interpret: bool = False):
     DEFAULT_BLOCK_PT, d=3 — 6- and 12-row blocks).  None of these row
     counts are sublane multiples, and Mosaic rejections are
     shape-dependent, so toy shapes would not certify the shapes the
-    solve compiles.  `interpret=True` runs the same kernel bodies under
-    the Pallas interpreter (the CPU test of this check).
+    solve compiles.  The point side runs twice: on a stream that
+    observes every point 5 times, as venice's, whose tiles span about
+    205 ids and so take the solve's 384-wide one-hot window (the last
+    tile of a block clamped at its end), and on a sparse random stream
+    whose tiles span their whole block.  The camera blocks take the
+    whole block.  `interpret=True` runs the same kernel bodies under the
+    Pallas interpreter (the CPU test of this check).
     """
     rng = np.random.default_rng(0)
     out = []
-    for side, tile, block, ns, d, od in (
-        ("cam", DEFAULT_TILE_CAM, DEFAULT_BLOCK_CAM, 200, 9, 2),
-        ("pt", DEFAULT_TILE_PT, DEFAULT_BLOCK_PT, 3000, 3, 2),
+    for side, tile, block, ns, d, od, degree in (
+        ("cam", DEFAULT_TILE_CAM, DEFAULT_BLOCK_CAM, 200, 9, 2, 0),
+        ("pt", DEFAULT_TILE_PT, DEFAULT_BLOCK_PT, 8000, 3, 2, 0),
+        ("pt", DEFAULT_TILE_PT, DEFAULT_BLOCK_PT, 2400, 3, 2, 5),
     ):
-        geometry = f"{side} tile={tile} block={block} d={d}"
-        n = 4 * tile  # several tiles; some blocks get >1 (accumulate)
-        idx = rng.integers(0, ns, n).astype(np.int32)
+        if degree:
+            idx = rng.permutation(np.repeat(np.arange(ns), degree))
+        else:
+            # Several tiles; some blocks get >1 (accumulate).
+            idx = rng.integers(0, ns, 4 * tile)
         plan = build_tile_plan(idx, ns, tile=tile, block=block)
+        geometry = (f"{side} tile={tile} block={block} "
+                    f"window={plan.window} d={d}")
         dp = device_plan(plan)
         m = jnp.asarray(plan.mask)
 

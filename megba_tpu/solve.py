@@ -564,6 +564,11 @@ def flat_solve(
                         plan_c.n_edges / max(1, plan_c.n_slots), 4),
                     **edge_stream_reuse(cam_idx, pt_idx, plan_c.block,
                                         plans.pt.block, mask=pmask),
+                    # One-hot width of the segtiles kernels per side:
+                    # the block unless the plan's tiles span less.
+                    "window_cam": plans.cam.window,
+                    "window_pt": plans.pt.window,
+                    "window_share_pt": plans.pt.window / plans.pt.block,
                 }
                 if fused:
                     tiles_info["fused_to_pt"] = fused_plan_summary(fp_tp)

@@ -30,11 +30,16 @@ VENICE_SLOTS = 5_013_504
 OD = 2  # BAL residual rows
 
 GEOMETRIES = {
-    # side: (tile, block, segments, vertex dim)
+    # side: (tile, block, segments, vertex dim, one-hot window).  The
+    # camera plan's window is its block; venice's point plan builds
+    # 384-wide windows (a tile spans 206 ids), and a point plan whose
+    # tiles span more takes its whole block.
     "cam": (segtiles.DEFAULT_TILE_CAM, segtiles.DEFAULT_BLOCK_CAM,
-            VENICE_CAMERAS, 9),
+            VENICE_CAMERAS, 9, segtiles.DEFAULT_BLOCK_CAM),
     "pt": (segtiles.DEFAULT_TILE_PT, segtiles.DEFAULT_BLOCK_PT,
-           VENICE_POINTS, 3),
+           VENICE_POINTS, 3, segtiles.DEFAULT_BLOCK_PT),
+    "pt_window384": (segtiles.DEFAULT_TILE_PT, segtiles.DEFAULT_BLOCK_PT,
+                     VENICE_POINTS, 3, 384),
 }
 
 
@@ -81,11 +86,13 @@ def compile_on(one_chip, chip_config):
     return run
 
 
-def _slot_arrays(tile, block, segments):
+def _slot_arrays(tile, block, segments, window):
+    """The plan operands' shapes: (local, per-tile array, static
+    arguments)."""
     n_tiles = VENICE_SLOTS // tile
     num_blocks = -(-segments // block)
     static = dict(tile=tile, block=block, num_blocks=num_blocks,
-                  interpret=False)
+                  interpret=False, window=window)
     local = ((1, VENICE_SLOTS), jnp.int32)
     tiles = ((n_tiles,), jnp.int32)
     return local, tiles, static
@@ -97,42 +104,42 @@ def _rows(n):
 
 @pytest.mark.parametrize("side", sorted(GEOMETRIES))
 def test_tile_reduce_compiles(compile_on, side):
-    tile, block, ns, d = GEOMETRIES[side]
-    local, tiles, static = _slot_arrays(tile, block, ns)
+    tile, block, ns, d, window = GEOMETRIES[side]
+    local, tiles, static = _slot_arrays(tile, block, ns, window)
     compile_on(segtiles._tile_reduce_call, _rows(d), local, tiles, tiles,
-               **static)
-
-
-@pytest.mark.parametrize("side", sorted(GEOMETRIES))
-def test_tile_expand_compiles(compile_on, side):
-    tile, block, ns, d = GEOMETRIES[side]
-    local, tiles, static = _slot_arrays(tile, block, ns)
-    compile_on(segtiles._tile_expand_call, ((d, ns), jnp.float32), local,
                tiles, **static)
 
 
 @pytest.mark.parametrize("side", sorted(GEOMETRIES))
+def test_tile_expand_compiles(compile_on, side):
+    tile, block, ns, d, window = GEOMETRIES[side]
+    local, tiles, static = _slot_arrays(tile, block, ns, window)
+    compile_on(segtiles._tile_expand_call, ((d, ns), jnp.float32), local,
+               tiles, tiles, **static)
+
+
+@pytest.mark.parametrize("side", sorted(GEOMETRIES))
 def test_jtj_grad_reduce_compiles(compile_on, side):
-    tile, block, ns, d = GEOMETRIES[side]
-    local, tiles, static = _slot_arrays(tile, block, ns)
+    tile, block, ns, d, window = GEOMETRIES[side]
+    local, tiles, static = _slot_arrays(tile, block, ns, window)
     compile_on(segtiles._jtj_reduce_call, _rows(OD * d), _rows(OD), local,
-               tiles, tiles, d=d, od=OD, **static)
+               tiles, tiles, tiles, d=d, od=OD, **static)
 
 
 @pytest.mark.parametrize("side", sorted(GEOMETRIES))
 def test_coupling_expand_compiles(compile_on, side):
-    tile, block, ns, d = GEOMETRIES[side]
-    local, tiles, static = _slot_arrays(tile, block, ns)
+    tile, block, ns, d, window = GEOMETRIES[side]
+    local, tiles, static = _slot_arrays(tile, block, ns, window)
     compile_on(segtiles._expand_matvec_call, _rows(OD * d),
-               ((d, ns), jnp.float32), local, tiles, d=d, **static)
+               ((d, ns), jnp.float32), local, tiles, tiles, d=d, **static)
 
 
 @pytest.mark.parametrize("side", sorted(GEOMETRIES))
 def test_coupling_reduce_compiles(compile_on, side):
-    tile, block, ns, d = GEOMETRIES[side]
-    local, tiles, static = _slot_arrays(tile, block, ns)
+    tile, block, ns, d, window = GEOMETRIES[side]
+    local, tiles, static = _slot_arrays(tile, block, ns, window)
     compile_on(segtiles._matvec_reduce_call, _rows(OD * d), _rows(OD),
-               local, tiles, tiles, d=d, **static)
+               local, tiles, tiles, tiles, d=d, **static)
 
 
 # Fused kernels: (in segments, in block, in dim) -> (out ...), per matvec

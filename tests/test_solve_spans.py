@@ -128,10 +128,13 @@ def test_tile_stats_only_when_a_report_is_written(monkeypatch, tmp_path):
     out = tmp_path / "t.jsonl"
     jax.block_until_ready(_solve(s, telemetry=str(out)))
     (line,) = out.read_text().splitlines()
-    # The block as the tree before this change wrote it for this scene.
+    # The block as the tree before this change wrote it for this scene,
+    # and the one-hot windows: 3 edges a point, so a 1,024-slot point
+    # tile spans about 341 ids and takes a 512-wide window.
     assert json.dumps(json.loads(line)["tiles"], sort_keys=True) == (
         '{"edges": 7500, "occupancy": 0.9155, "plan": "tiled_1d", '
-        '"reuse_factor": 312.5, "switches": 24}')
+        '"reuse_factor": 312.5, "switches": 24, "window_cam": 128, '
+        '"window_pt": 512, "window_share_pt": 0.25}')
 
 
 # ------------------------------------------------------ compiled program
