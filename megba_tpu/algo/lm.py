@@ -261,7 +261,10 @@ def lm_solve(
     guards = robust_opt.guards
 
     def psum(x):
-        return jax.lax.psum(x, axis_name) if axis_name is not None else x
+        if axis_name is None:
+            return x
+        with jax.named_scope("megba.collective"):
+            return jax.lax.psum(x, axis_name)
 
     robust = option.robust_kind
     robust_delta = option.robust_delta

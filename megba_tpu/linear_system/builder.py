@@ -223,8 +223,9 @@ def build_schur_system(
     g_pt = hll_acc[pd * pd:]
 
     if axis_name is not None:
-        hpp_rows, g_cam, Hll, g_pt = jax.lax.psum(
-            (hpp_rows, g_cam, Hll, g_pt), axis_name)
+        with jax.named_scope("megba.collective"):
+            hpp_rows, g_cam, Hll, g_pt = jax.lax.psum(
+                (hpp_rows, g_cam, Hll, g_pt), axis_name)
 
     # Camera blocks to batched [Nc, cd, cd] (small; dense-block ops and
     # the 9x9 Cholesky inverse want this form).

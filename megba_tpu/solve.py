@@ -179,9 +179,9 @@ def flat_solve(
     dispatch — "dispatch" includes jit tracing+compilation on the first
     call of a configuration), and inside them the sub-phases plan.key
     (index digest and plan-cache lookup), plan.gather (edges into plan
-    order), lowering.transpose (feature-major operands), and on one
-    device dispatch.upload (index and mask operands) and dispatch.call
-    (the jitted call, host operands' transfer included).  With
+    order), lowering.transpose (feature-major operands), on one device
+    dispatch.upload (index and mask operands), and dispatch.call (the
+    jitted call, host operands' transfer included).  With
     telemetry enabled (MEGBA_TELEMETRY=<path> or `option.telemetry`) an
     extra blocking "execute" phase is timed and a SolveReport JSONL line
     is appended; with it disabled the solve stays fully asynchronous and
@@ -500,6 +500,23 @@ def flat_solve(
                 else:
                     mask = masks.reshape(-1).astype(dtype)
             n_padded = obs.shape[0]
+            if telemetry:
+                # Per-shard counters of the sharded plans (SolveReport.
+                # tiles): each shard's real edges in its slots (every
+                # shard has the same slot count), and the one-hot windows,
+                # which every shard runs at the widest shard's width.
+                edges = [int(m.sum()) for m in masks]
+                slots = int(masks.shape[1])
+                pt = plans.pt
+                tiles_info = {
+                    "plan": "tiled_sharded",
+                    "shard_edges": edges,
+                    "shard_slots": slots,
+                    "shard_occupancy": [round(e / slots, 4) for e in edges],
+                    "window_cam": plans.cam.window,
+                    "window_pt": pt.window,
+                    "window_share_pt": pt.window / pt.block,
+                }
     elif use_tiled:
         # Tiled lowering: the cam plan's slot order IS the edge axis from
         # here on (it subsumes the camera sort and quantum padding).
@@ -711,7 +728,8 @@ def flat_solve(
                 initial_region=initial_region, initial_v=initial_v,
                 initial_dx=initial_dx_j, fault_plan=fault_j,
                 cluster_plan=cluster_plan_j, tile_plan=tile_plan_j,
-                jit_cache=jit_cache, donate=True, lower_only=lower_only)
+                jit_cache=jit_cache, donate=True, lower_only=lower_only,
+                timer=timer)
         if lower_only:
             return result
         result = _result_to_edge_major(result)

@@ -277,7 +277,8 @@ def make_coupling_matvecs(
     def psum(x):
         if axis_name is None:
             return x
-        return wire_up(jax.lax.psum(wire_down(x), axis_name))
+        with jax.named_scope("megba.collective"):
+            return wire_up(jax.lax.psum(wire_down(x), axis_name))
 
     if use_fused:
         # Fused edge-pipeline dispatch (ops/fused.py): ONE kernel per
@@ -575,9 +576,10 @@ def make_matvec_2d(
         # wire casts compress both stage payloads to bf16 under the
         # collective gate (the shard stays compressed between the two).
         t_part = segsum_fm(te, pt_idx, np_pad)
-        t_sh = jax.lax.psum_scatter(wire_down(t_part), cam_axis,
-                                    scatter_dimension=1, tiled=True)
-        t_sh = wire_up(jax.lax.psum(t_sh, edge_axis))  # [pd, Sp] owned shard
+        with jax.named_scope("megba.collective"):
+            t_sh = jax.lax.psum_scatter(wire_down(t_part), cam_axis,
+                                        scatter_dimension=1, tiled=True)
+            t_sh = wire_up(jax.lax.psum(t_sh, edge_axis))  # [pd, Sp] owned
         # (3) Hll^-1 on the owned shard.
         hll_sh = jax.lax.dynamic_slice_in_dim(
             Hll_inv_pad, ci * Sp, Sp, axis=1)
@@ -590,8 +592,10 @@ def make_matvec_2d(
         cur = wire_down(cur)
         tile_acc = jnp.zeros((cd, Tc), p.dtype)
         for j in range(C):
-            nxt = (jax.lax.ppermute(cur, cam_axis, perm=ring)
-                   if j < C - 1 else cur)
+            nxt = cur
+            if j < C - 1:
+                with jax.named_scope("megba.collective"):
+                    nxt = jax.lax.ppermute(cur, cam_axis, perm=ring)
             s = (ci + j) % C  # j, C static ints: stays the index dtype
             slot = jax.lax.dynamic_slice_in_dim(
                 tile_plan.bucket_slot, s, 1, axis=0)[0]
@@ -642,12 +646,14 @@ def make_matvec_2d(
         # (5) camera reduction: EDGE-subgroup psum of the tile, one
         # all_gather over CAM re-replicates (both payloads wire-cast
         # under the collective gate).
-        hpl_t = wire_up(jax.lax.psum(wire_down(tile_acc), edge_axis))
+        with jax.named_scope("megba.collective"):
+            hpl_t = wire_up(jax.lax.psum(wire_down(tile_acc), edge_axis))
         y_t = cam_block_matvec(
             jax.lax.dynamic_slice_in_dim(Hpp_pad, ci * Tc, Tc, axis=0),
             p_t) - hpl_t
-        y = wire_up(all_gather_invariant(wire_down(y_t), cam_axis,
-                                         axis=1, tiled=True))
+        with jax.named_scope("megba.collective"):
+            y = wire_up(all_gather_invariant(wire_down(y_t), cam_axis,
+                                             axis=1, tiled=True))
         return y[:, :num_cameras]
 
     return s_matvec

@@ -280,7 +280,8 @@ def _schur_diag_precond(
     (corr_rows,) = chunked_edge_reduce(
         nE, (jnp.zeros((cd * cd, num_cameras), dtype),), body)
     if axis_name is not None:
-        corr_rows = jax.lax.psum(corr_rows, axis_name)
+        with jax.named_scope("megba.collective"):
+            corr_rows = jax.lax.psum(corr_rows, axis_name)
     corr = jnp.moveaxis(corr_rows.reshape(cd, cd, num_cameras), -1, 0)
     # In exact arithmetic Hpp_d - corr is SPD (a principal block of S),
     # but rounding (especially equilibrated bf16 operands) can push a
@@ -384,7 +385,8 @@ def _smooth_correction(
             nE, (jnp.zeros((pd, Np, mc), dtype),), ubody,
             target=edge_target)
         if axis_name is not None:
-            U = jax.lax.psum(U, axis_name)
+            with jax.named_scope("megba.collective"):
+                U = jax.lax.psum(U, axis_name)
         T = jnp.einsum("qsp,spm->qpm", hinv, U,
                        precision=HI)  # Hll⁻¹ · (Hlp Y)
 
@@ -406,7 +408,8 @@ def _smooth_correction(
         z_cols.append(Zb)
     Zcoup = jnp.concatenate(z_cols, axis=2) if len(z_cols) > 1 else z_cols[0]
     if axis_name is not None:
-        Zcoup = jax.lax.psum(Zcoup, axis_name)
+        with jax.named_scope("megba.collective"):
+            Zcoup = jax.lax.psum(Zcoup, axis_name)
     Z1 = jnp.einsum("nac,cnJb->anJb", Hpp_d, Y, precision=HI)
     return Z1 - Zcoup.reshape(cd, num_cameras, C, cd)
 
@@ -480,7 +483,8 @@ def build_two_level_coarse(
     (V,) = chunked_edge_reduce(
         n_edges, (jnp.zeros((cd * pd, n_pc), dtype),), vbody)
     if axis_name is not None:
-        V = jax.lax.psum(V, axis_name)
+        with jax.named_scope("megba.collective"):
+            V = jax.lax.psum(V, axis_name)
 
     # T = V · Hll⁻¹ per incidence (the point block is shared by every
     # incidence of its point; Hll⁻¹ is symmetric, so T's columns double
@@ -515,7 +519,8 @@ def build_two_level_coarse(
         cluster_plan.ec_edge.shape[0],
         (jnp.zeros((cd * cd, NcC), dtype),), gbody, target=_PAIR_CHUNK)
     if axis_name is not None:
-        corrg_rows = jax.lax.psum(corrg_rows, axis_name)
+        with jax.named_scope("megba.collective"):
+            corrg_rows = jax.lax.psum(corrg_rows, axis_name)
     corrg = corrg_rows.reshape(cd, cd, num_cameras, C).transpose(0, 2, 3, 1)
 
     # Fine half Hpp_d Rᵀ: Hpp is block diagonal, so camera n contributes
