@@ -1375,17 +1375,14 @@ def main() -> None:
     cmp = head_to_heads(sc, res, elapsed, timer)
     # Charge the reference model the S·p products this run actually
     # executed (the PCG can exit below the 30-iteration cap), so both
-    # sides of vs_baseline do the same algorithmic work.  The fused
-    # Chronopoulos-Gear body performs iterations+1 matvecs per PCG
-    # solve (one pre-loop product primes the recurrence), so the model
-    # is charged the +1 too — otherwise vs_baseline would flatter this
-    # implementation by one uncharged matvec per LM iteration.
+    # sides of vs_baseline do the same algorithmic work: the fused PCG
+    # body performs one matvec per iteration.
     measured_pcg_per_lm = float(res.pcg_iterations) / max(iters, 1)
     baseline = derived_baseline_lm_iters_per_sec(
         n_edge=n_edge,
         n_cam=NUM_CAMERAS,
         n_pt=NUM_POINTS,
-        pcg_iters=measured_pcg_per_lm + 1.0,
+        pcg_iters=measured_pcg_per_lm,
         ref_dtype_bytes=_C.ref_dtype_bytes,
         implicit=_C.ref_implicit,
     )

@@ -673,36 +673,37 @@ def _pcg_core(matvec, precond, b, max_iter, tol, refuse_ratio, tol_relative,
     (vector = a (camera, point) pair).  Returns
     (x, iterations, rho, r0_ratio, restarts, broken).
 
+    The body is the Chronopoulos-Gear single-recurrence CG: carrying the
+    auxiliary direction s = A p alongside p lets each iteration run as
+    ONE pass.  The matvec comes first: w = A u and delta = <u, w>, then
+    alpha from the scalar recurrence alpha = gamma / (delta - beta *
+    gamma / alpha_prev) with beta = gamma / gamma_prev, four fused axpys
+    (p, s, x, r), the one preconditioner apply u = M^-1 r and the next
+    gamma = <r, u>.  The first iteration takes classic CG's first step
+    (p = u, s = A u, alpha = gamma / delta).  Iterates are identical to
+    textbook PCG in exact arithmetic, and k iterations run exactly k
+    matvecs: the product an iteration that `cond` refuses would need is
+    never computed.  The matvec stays the only collective site, so the
+    census invariant — exactly 2 all-reduces per S·p inside the while
+    body (analysis/program_audit.py pass 2) — is unchanged.
+
     `guard=True` (RobustOption.guards) arms breakdown detection on the
-    Chronopoulos-Gear scalars: a non-finite or sign-flipped gamma
-    (rho_new) / delta means the recurrence has left the SPD regime, and
-    the guard performs an in-loop COLD RESTART from the current iterate
-    — the next two body iterations repurpose the body's single matvec
-    slot to (1) recompute the true residual r = b - A x and (2) re-prime
-    the recurrence (p = M^-1 r, s = A p, alpha = rho/delta), then CG
-    resumes.  At most `max_restarts` restarts; one more breakdown exits
-    with `broken=True` and the best iterate.  The matvec stays the only
-    collective site and restart iterations use the SAME slot, so the
+    Chronopoulos-Gear scalars: a non-finite or sign-flipped gamma or
+    delta means the recurrence has left the SPD regime, and the guard
+    performs an in-loop COLD RESTART from the current iterate: one
+    body iteration repurposes the body's single matvec slot to
+    recompute the true residual r = b - A x (with u = M^-1 r and
+    gamma), and the next takes the first-iteration step from it.  A
+    step whose delta broke is not taken.  At most `max_restarts`
+    restarts; one more breakdown exits with `broken=True` and the best
+    iterate.  Restart iterations use the SAME matvec slot, so the
     per-body-iteration collective census (2 all-reduces for the Schur
     S.p) is unchanged — the `ba_guarded_w2_f32` canonical program pins
     exactly this.  When no breakdown fires every selected value is
     bitwise identical to the unguarded body.
 
-    The body is the Chronopoulos-Gear single-recurrence CG: carrying the
-    auxiliary direction s = A p alongside p lets each iteration run as
-    ONE pass — four fused axpys (x, r, p, s), one preconditioner apply,
-    one matvec, and BOTH compensated dots (<r, u> and <u, w>) computed
-    back-to-back on the freshly produced u/w instead of at two separate
-    reduction points, with alpha recovered by the scalar recurrence
-    alpha = gamma / (delta - beta * gamma / alpha_prev).  Iterates are
-    identical to textbook PCG in exact arithmetic; the matvec count is
-    k+1 (one extra A·u before the loop primes the recurrence).  The
-    matvec stays the only collective site, so the census invariant —
-    exactly 2 all-reduces per S·p inside the while body
-    (analysis/program_audit.py pass 2) — is unchanged.
-
-    `x0` warm-starts the iteration (r0 = b - A x0; one extra matvec,
-    also outside the while body).  `tol_relative` anchors the threshold
+    `x0` warm-starts the iteration (r0 = b - A x0: the one matvec
+    outside the while body).  `tol_relative` anchors the threshold
     to the RHS energy <b, M^-1 b> — NOT the initial-guess residual
     rho0, which a good warm start drives toward _TINY_RHO and which
     would therefore either exit spuriously after 0 iterations or
@@ -720,12 +721,10 @@ def _pcg_core(matvec, precond, b, max_iter, tol, refuse_ratio, tol_relative,
     iteration: same per-iteration op counts (one matvec, one precond
     apply, two compensated dots) and the matvec stays the only
     collective site (2 all-reduces per S·p in the body — the
-    `ba_bf16_w2_f32` canonical program pins it), the dots are merely
-    sequential instead of back-to-back.  Warm starts, refuse, guards
-    and restarts keep their semantics; a guarded restart costs ONE
-    body iteration here (classic CG restarts by refreshing r = b - A x
-    and re-seeding p = M⁻¹ r — there is no auxiliary recurrence to
-    re-prime).
+    `ba_bf16_w2_f32` canonical program pins it).  Warm starts, refuse,
+    guards and restarts keep their semantics; a guarded restart is ONE
+    refresh iteration in both bodies (classic CG refreshes r = b - A x
+    and re-seeds p = M⁻¹ r).
     """
     tm = jax.tree_util.tree_map
 
@@ -783,121 +782,134 @@ def _pcg_core(matvec, precond, b, max_iter, tol, refuse_ratio, tol_relative,
             x_init, r0, u0, rho0, rhs_energy, r0_ratio,
             guard, max_restarts, tdot, axpy, select)
 
-    # Prime the Chronopoulos-Gear recurrence: p0 = u0, s0 = A p0,
-    # alpha0 = rho0 / <p0, A p0> — exactly classic CG's first alpha.
-    # (Guard the division: u0 = 0 on a zero residual, where the loop
-    # below never runs and alpha is never consumed.)
-    w0 = matvec(u0)
-    delta0 = tdot(u0, w0)
-    alpha0 = rho0 / jnp.where(delta0 == 0, jnp.ones_like(delta0), delta0)
+    # The matvec is the FIRST step of the body, so the loop runs exactly
+    # one product per CG iteration: the product of an iteration that
+    # `cond` refuses is never computed.  The body's first iteration (and,
+    # under the guard, the first after a restart refresh) takes classic
+    # CG's first step: p = u, s = A u, alpha = rho / <u, A u> (the
+    # division guarded for an operator that annihilates u).
+    zeros = tm(jnp.zeros_like, u0)
+    one = jnp.ones_like(rho0)
+
+    # u = M^-1 r crosses the loop boundary transposed: a (d, n) leaf is
+    # carried in the (n, d) orientation in which the block-diagonal
+    # einsums (`nij,jn->in`) read it, so the matvec that opens the body
+    # reads the carried value with no relayout between them.  (Carried
+    # as (d, n), XLA's CPU backend puts a transpose of the loop
+    # parameter there, an instruction of no layer; on the TPU both
+    # orientations compile to the same physical layout.)
+    def flip(v):
+        return tm(jnp.transpose, v)
+
+    def cg_step(first, x, r, u, rho, rho_prev, alpha_prev, p, s, w):
+        """One Chronopoulos-Gear step from w = A u: the scalar recurrence
+        alpha = rho / (delta - beta * rho / alpha_prev), then the four
+        fused axpys (p, s, x, r)."""
+        delta = tdot(u, w)
+        beta = jnp.where(first, jnp.zeros_like(rho), rho / rho_prev)
+        alpha = jnp.where(
+            first, rho / jnp.where(delta == 0, one, delta),
+            rho / (delta - beta * rho / alpha_prev))
+        p = axpy(beta, p, u)  # u + beta p: u on a first step, p = 0
+        s = axpy(beta, s, w)  # w + beta s == A p
+        return delta, alpha, p, s, axpy(alpha, p, x), axpy(-alpha, s, r)
 
     if not guard:
-        state0 = (jnp.int32(0), x_init, r0, u0, w0, alpha0, rho0,
-                  jnp.abs(rho0), x_init, jnp.bool_(False))
+        state0 = (jnp.int32(0), x_init, r0, flip(u0), rho0, rho0, one,
+                  zeros, zeros, jnp.abs(rho0), x_init, jnp.bool_(False))
 
         def cond(state):
-            k, _, _, _, _, _, rho, _, _, refused = state
+            k, _, _, _, rho, _, _, _, _, _, _, refused = state
             return (k < max_iter) & (jnp.abs(rho) >= threshold) & (~refused)
 
         def body(state):
-            k, x, r, p, s, alpha, rho, rho_min, x_best, _ = state
-            # One fused vector pass: both solution/residual updates...
-            x = axpy(alpha, p, x)
-            r = axpy(-alpha, s, r)
-            # ...then the only preconditioner apply and the only matvec
-            # (the sole collective site: 2 psums inside the Schur S·p)...
-            u = precond(r)
+            (k, x, r, u, rho, rho_prev, alpha_prev, p, s, rho_min, x_best,
+             _) = state
+            u = flip(u)
+            # The only matvec (the sole collective site: 2 psums inside
+            # the Schur S·p), then one fused vector pass...
             w = matvec(u)
-            # ...and both compensated dots on the same fresh u/w.
+            _, alpha, p, s, x, r = cg_step(k == 0, x, r, u, rho, rho_prev,
+                                           alpha_prev, p, s, w)
+            # ...the only preconditioner apply and the residual's dot.
+            u = precond(r)
             rho_new = tdot(r, u)
-            delta = tdot(u, w)
-            beta = rho_new / rho
-            alpha = rho_new / (delta - beta * rho_new / alpha)
-            p = axpy(beta, p, u)  # u + beta p
-            s = axpy(beta, s, w)  # w + beta s == A p, by linearity
             refused = jnp.abs(rho_new) > refuse_ratio * rho_min
             improved = jnp.abs(rho_new) < rho_min
             rho_min = jnp.where(improved, jnp.abs(rho_new), rho_min)
             x_best = select(improved, x, x_best)
-            return (k + 1, x, r, p, s, alpha, rho_new, rho_min, x_best,
-                    refused)
+            return (k + 1, x, r, flip(u), rho_new, rho, alpha, p, s,
+                    rho_min, x_best, refused)
 
-        (k, x, _, _, _, _, rho, _, x_best, refused) = jax.lax.while_loop(
-            cond, body, state0)
+        (k, x, _, _, rho, _, _, _, _, _, x_best,
+         refused) = jax.lax.while_loop(cond, body, state0)
         return (select(~refused, x, x_best), k, rho, r0_ratio,
                 jnp.int32(0), jnp.bool_(False))
 
     # ---- guarded body (RobustOption.guards) -----------------------------
-    # A 3-mode branchless body: phase 0 = normal CG step, phase 1 = the
-    # restart's residual refresh (the matvec slot computes A x and
-    # r := b - A x), phase 2 = recurrence re-prime (p = M^-1 r, s = A p,
-    # alpha = rho / delta).  Every mode runs the SAME one precond + one
-    # matvec, so the body's collective census is identical to the
-    # unguarded body; a phase-0 run with no breakdown selects exactly
-    # the unguarded values, bitwise.
+    # A 3-mode branchless body: phase 0 = CG step, phase 1 = the
+    # restart's residual refresh (the matvec slot computes A x, then
+    # r := b - A x, u = M^-1 r, rho = <r, u>), phase 2 = the first CG
+    # step, taken on entry and after every refresh.  Every mode runs the
+    # SAME one matvec + one precond, so the body's collective census is
+    # identical to the unguarded body's; with no breakdown every selected
+    # value is the unguarded one, bitwise.
     threshold_arr = jnp.asarray(threshold, rho0.dtype)
-    # Keep-alive rho carried through restart iterations: strictly above
-    # the exit threshold so cond cannot fire on a placeholder, finite by
+    # Keep-alive rho carried out of a breakdown: strictly above the exit
+    # threshold so cond cannot fire on a placeholder, finite by
     # construction (|rhs_energy| is, or the solve was empty).
     keepalive = jnp.maximum(jnp.abs(rhs_energy), threshold_arr) * 2.0 + 1.0
     minus_one = jnp.asarray(-1.0, rho0.dtype)
 
-    state0 = (jnp.int32(0), x_init, r0, u0, w0, alpha0, rho0,
-              jnp.abs(rho0), x_init, jnp.bool_(False),
-              jnp.int32(0), jnp.int32(0), jnp.bool_(False))
+    state0 = (jnp.int32(0), x_init, r0, flip(u0), rho0, rho0, one, zeros,
+              zeros, jnp.abs(rho0), x_init, jnp.bool_(False),
+              jnp.int32(2), jnp.int32(0), jnp.bool_(False))
 
     def cond(state):
-        k, _, _, _, _, _, rho, _, _, refused, _, _, broken = state
+        k, _, _, _, rho, _, _, _, _, _, _, refused, _, _, broken = state
         return ((k < max_iter) & (jnp.abs(rho) >= threshold)
                 & (~refused) & (~broken))
 
     def body(state):
-        (k, x, r, p, s, alpha, rho, rho_min, x_best, refused,
-         phase, restarts, broken) = state
-        advancing = phase == 0
+        (k, x, r, u, rho, rho_prev, alpha_prev, p, s, rho_min, x_best,
+         refused, phase, restarts, broken) = state
+        u = flip(u)
         refresh = phase == 1
-        reprime = phase == 2
-        # Phase 0 applies the pending CG update; restart phases hold x/r.
-        step = jnp.where(advancing, alpha, jnp.zeros_like(alpha))
-        x = axpy(step, p, x)
-        r = axpy(-step, s, r)
-        u = precond(r)
         # The one matvec: A u normally, A x during the residual refresh.
         w = matvec(select(refresh, x, u))
-        r = select(refresh, axpy(minus_one, w, b), r)  # b - A x
-        rho_new = tdot(r, u)  # garbage during refresh (u is stale): masked
-        delta = tdot(u, w)
-        beta = rho_new / rho
-        alpha_cg = rho_new / (delta - beta * rho_new / alpha)
-        alpha_fresh = rho_new / jnp.where(
-            delta == 0, jnp.ones_like(delta), delta)
-        # Breakdown: the SPD invariants gamma = <r, M^-1 r> >= 0 and
-        # delta = <p, A p> >= 0 broke, or the recurrence scalars left
-        # the finite range.  Refresh iterations produce no real scalars.
-        breakdown = (~refresh) & (
-            ~(jnp.isfinite(rho_new) & jnp.isfinite(delta))
-            | (rho_new < 0) | (delta < 0))
+        delta, alpha, p, s, x_new, r_new = cg_step(
+            phase == 2, x, r, u, rho, rho_prev, alpha_prev, p, s, w)
+        # Breakdown: the SPD invariants delta = <u, A u> >= 0 and
+        # gamma = <r, M^-1 r> >= 0 broke, or the scalars left the finite
+        # range.  A refresh's delta is no real scalar (w is A x); a step
+        # whose delta broke is not taken.
+        delta_bad = (~refresh) & (~jnp.isfinite(delta) | (delta < 0))
+        take = (~refresh) & (~delta_bad)
+        x = select(take, x_new, x)
+        # A step not taken leaves p = s = 0 for the first step after it.
+        p = select(take, p, zeros)
+        s = select(take, s, zeros)
+        r = select(refresh, axpy(minus_one, w, b),  # b - A x
+                   select(take, r_new, r))
+        u = precond(r)
+        rho_new = tdot(r, u)
+        breakdown = delta_bad | ~jnp.isfinite(rho_new) | (rho_new < 0)
         enter = breakdown & (restarts < max_restarts)
         broken = broken | (breakdown & (restarts >= max_restarts))
         phase_next = jnp.where(enter, jnp.int32(1),
                                jnp.where(refresh, jnp.int32(2),
                                          jnp.int32(0)))
         restarts = restarts + enter.astype(jnp.int32)
-        ok_adv = advancing & ~breakdown
-        ok_rep = reprime & ~breakdown
-        alpha = jnp.where(ok_rep, alpha_fresh,
-                          jnp.where(ok_adv, alpha_cg, alpha))
-        rho_next = jnp.where(enter | refresh, keepalive, rho_new)
-        p = select(ok_rep, u, select(ok_adv, axpy(beta, p, u), p))
-        s = select(ok_rep, w, select(ok_adv, axpy(beta, s, w), s))
-        refused = ok_adv & (jnp.abs(rho_new) > refuse_ratio * rho_min)
-        improved = ok_adv & (jnp.abs(rho_new) < rho_min)
+        ok = take & ~breakdown
+        rho_next = jnp.where(enter, keepalive, rho_new)
+        refused = ok & (jnp.abs(rho_new) > refuse_ratio * rho_min)
+        improved = ok & (jnp.abs(rho_new) < rho_min)
         rho_min = jnp.where(improved, jnp.abs(rho_new), rho_min)
         x_best = select(improved, x, x_best)
-        return (k + 1, x, r, p, s, alpha, rho_next, rho_min, x_best,
-                refused, phase_next, restarts, broken)
+        return (k + 1, x, r, flip(u), rho_next, rho, alpha, p, s, rho_min,
+                x_best, refused, phase_next, restarts, broken)
 
-    (k, x, _, _, _, _, rho, _, x_best, refused, _, restarts,
+    (k, x, _, _, rho, _, _, _, _, _, x_best, refused, _, restarts,
      broken) = jax.lax.while_loop(cond, body, state0)
     return (select(~refused & ~broken, x, x_best), k, rho, r0_ratio,
             restarts, broken)

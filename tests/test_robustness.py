@@ -23,6 +23,7 @@ import dataclasses
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from megba_tpu.common import (
@@ -220,6 +221,41 @@ def test_pcg_core_guard_is_bitwise_free_and_flags_indefinite():
     _, _, _, _, re2, br2 = run(indef, True, max_restarts=2)
     assert bool(br2)
     assert int(re2) == 2
+
+
+def test_pcg_core_guard_restart_converges_to_dense_answer():
+    """One poisoned product mid-solve: the guarded body restarts once
+    (residual refresh, then a first CG step from the refreshed residual)
+    and still converges to the dense solution, one product per trip."""
+    from jax.experimental import io_callback
+
+    from megba_tpu.solver.pcg import _pcg_core
+
+    rng = np.random.default_rng(1)
+    a = rng.standard_normal((12, 12))
+    spd = np.asarray(a @ a.T + 12 * np.eye(12))
+    b = rng.standard_normal(12)
+    calls = [0]
+
+    def scale():
+        # The third product comes back NaN: its delta = <u, A u> is
+        # non-finite, a breakdown.
+        calls[0] += 1
+        return np.float64(np.nan if calls[0] == 3 else 1.0)
+
+    def matvec(v):
+        return (jnp.asarray(spd) @ v) * io_callback(
+            scale, jax.ShapeDtypeStruct((), jnp.float64))
+
+    x, k, rho, _, restarts, broken = _pcg_core(
+        matvec, lambda r: r, jnp.asarray(b), 60, 1e-20, 1e30, False,
+        guard=True, max_restarts=2)
+    jax.effects_barrier()
+    assert int(restarts) == 1 and not bool(broken)
+    assert abs(float(rho)) < 1e-20
+    assert calls[0] == int(k)
+    np.testing.assert_allclose(np.asarray(x), np.linalg.solve(spd, b),
+                               rtol=1e-9, atol=1e-12)
 
 
 # ----------------------------------------------- preconditioner fallback

@@ -252,3 +252,225 @@ def test_mixed_precision_validation_pipeline(tmp_path):
     payload = json.loads(open(out_path).read())
     assert payload["pass"] is True
     assert "bf16_coupling" in payload["runs"] and "f32" in payload["runs"]
+
+
+# ---------------------------------------------- fused CG: k products for k
+# iterations, iterates unchanged
+
+
+def _pcg_k_plus_1(matvec, precond, b, max_iter, tol, refuse_ratio,
+                  tol_relative, x0=None, **_):
+    """The fused (Chronopoulos-Gear) PCG as it ran with its matvec at the
+    END of the loop body: one product before the loop primed the
+    recurrence, so k iterations ran k+1 products.  The reference the
+    matvec-first body must reproduce bit for bit; it returns _pcg_core's
+    tuple (restarts 0, broken False: clean solves only)."""
+    from megba_tpu.solver.pcg import _TINY_RHO, _dot
+
+    tm = jax.tree_util.tree_map
+
+    def tdot(a, c):
+        return jax.tree_util.tree_reduce(lambda acc, v: acc + v,
+                                         tm(_dot, a, c))
+
+    def axpy(a, x, y):
+        return tm(lambda xi, yi: yi + a * xi, x, y)
+
+    def select(pred, a, c):
+        return tm(lambda ai, ci: jnp.where(pred, ai, ci), a, c)
+
+    if x0 is None:
+        x_init, r0 = tm(jnp.zeros_like, b), b
+        u0 = precond(r0)
+        rho0 = tdot(r0, u0)
+        rhs_energy, r0_ratio = rho0, jnp.ones_like(rho0)
+    else:
+        r0 = axpy(jnp.asarray(-1.0, jax.tree_util.tree_leaves(b)[0].dtype),
+                  matvec(x0), b)
+        u0 = precond(r0)
+        rho0 = tdot(r0, u0)
+        ub = precond(b)
+        rhs_energy = tdot(b, ub)
+        r0_ratio = jnp.abs(rho0) / jnp.maximum(
+            jnp.abs(rhs_energy), jnp.asarray(_TINY_RHO, rho0.dtype))
+        use_ws = jnp.abs(rho0) <= jnp.abs(rhs_energy)
+        x_init = select(use_ws, x0, tm(jnp.zeros_like, b))
+        r0 = select(use_ws, r0, b)
+        u0 = select(use_ws, u0, ub)
+        rho0 = jnp.where(use_ws, rho0, rhs_energy)
+    threshold = (jnp.maximum(tol * jnp.abs(rhs_energy),
+                             jnp.asarray(_TINY_RHO, rho0.dtype))
+                 if tol_relative else tol)
+    w0 = matvec(u0)
+    delta0 = tdot(u0, w0)
+    alpha0 = rho0 / jnp.where(delta0 == 0, jnp.ones_like(delta0), delta0)
+
+    def cond(state):
+        k, _, _, _, _, _, rho, _, _, refused = state
+        return (k < max_iter) & (jnp.abs(rho) >= threshold) & (~refused)
+
+    def body(state):
+        k, x, r, p, s, alpha, rho, rho_min, x_best, _ = state
+        x = axpy(alpha, p, x)
+        r = axpy(-alpha, s, r)
+        u = precond(r)
+        w = matvec(u)
+        rho_new = tdot(r, u)
+        delta = tdot(u, w)
+        beta = rho_new / rho
+        alpha = rho_new / (delta - beta * rho_new / alpha)
+        p = axpy(beta, p, u)
+        s = axpy(beta, s, w)
+        refused = jnp.abs(rho_new) > refuse_ratio * rho_min
+        improved = jnp.abs(rho_new) < rho_min
+        rho_min = jnp.where(improved, jnp.abs(rho_new), rho_min)
+        x_best = select(improved, x, x_best)
+        return (k + 1, x, r, p, s, alpha, rho_new, rho_min, x_best, refused)
+
+    state0 = (jnp.int32(0), x_init, r0, u0, w0, alpha0, rho0, jnp.abs(rho0),
+              x_init, jnp.bool_(False))
+    k, x, _, _, _, _, rho, _, x_best, refused = jax.lax.while_loop(
+        cond, body, state0)
+    return (select(~refused, x, x_best), k, rho, r0_ratio, jnp.int32(0),
+            jnp.bool_(False))
+
+
+def _dense_spd(seed=0, n=12, shift=None):
+    rng = np.random.default_rng(seed)
+    a = rng.standard_normal((n, n))
+    shift = 0.5 * n if shift is None else shift
+    mat = jnp.asarray(a @ a.T + shift * np.eye(n))
+    b = jnp.asarray(rng.standard_normal(n))
+    return mat, b
+
+
+def _counting(matvec):
+    """Wrap `matvec` so every EXECUTED product (eager, or one trip of a
+    while body) bumps a host-side counter."""
+    calls = [0]
+
+    def bump():
+        calls[0] += 1
+
+    def counted(v):
+        jax.debug.callback(bump)
+        return matvec(v)
+
+    return counted, calls
+
+
+# Dense cases: diagonal shift, tol, refuse_ratio and whether to
+# warm-start.  "refused" is ill-conditioned enough that the refuse guard
+# (rho grew) stops it before tol or max_iter.
+_DENSE_CASES = {
+    "cold": (8.0, 1e-20, 1e30, False),
+    "warm": (8.0, 1e-20, 1e30, True),
+    "refused": (0.05, 1e-30, 1.0, False),
+}
+
+
+@pytest.mark.parametrize("guard", [False, True])
+@pytest.mark.parametrize("case", sorted(_DENSE_CASES))
+def test_fused_pcg_bitwise_matches_k_plus_1_recurrence_dense(case, guard):
+    from megba_tpu.solver.pcg import _pcg_core
+
+    shift, tol, refuse, warm = _DENSE_CASES[case]
+    mat, b = _dense_spd(seed=0, n=16, shift=shift)
+    diag = jnp.diagonal(mat)
+    x0 = 0.5 * jnp.linalg.solve(mat, b) if warm else None
+    args = (lambda v: mat @ v, lambda r: r / diag)
+    rest = (60, tol, refuse, False)
+    # Jitted, as every caller runs it (see _SCHUR_CASES).
+    ref = jax.jit(lambda b, x0: _pcg_k_plus_1(*args, b, *rest, x0=x0))(
+        b, x0)
+    new = jax.jit(lambda b, x0: _pcg_core(
+        *args, b, *rest, x0=x0, guard=guard, max_restarts=2))(b, x0)
+    for got, want in zip(new[:4], ref[:4]):
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+    assert int(new[4]) == 0 and not bool(new[5])
+    k = int(new[1])
+    assert 1 < k < 60
+    if case == "refused":
+        assert abs(float(new[2])) >= tol  # the guard stopped it, not tol
+
+
+# Schur cases on a small BA system: region, tol, refuse_ratio, warm start.
+# Each solve is jitted, as every caller runs it: op-by-op (eager) the
+# priming product outside the loop rounds differently from the same
+# product compiled into the loop body.  The warm start's first CG step
+# is the one place where the two bodies are not bit for bit alike on
+# XLA:CPU: the first step's compensated dots (<r0, u0>, <u0, A u0>) are
+# compiled next to the warm-start residual product in one body and
+# inside the loop in the other, XLA:CPU contracts their multiply-adds
+# into FMAs by fusion context, and alpha0 comes out one ulp apart.  That
+# case is held to 1e-11 instead; its count and r0_ratio stay exact.
+_SCHUR_CASES = {
+    "cold": (100.0, 1e-14, 1e30, False),
+    "warm": (100.0, 1e-6, 1e30, True),
+    "refused": (1e3, 1e-30, 1.0, False),
+}
+
+
+@pytest.mark.parametrize("guard", [False, True])
+@pytest.mark.parametrize("case", sorted(_SCHUR_CASES))
+def test_fused_pcg_bitwise_matches_k_plus_1_recurrence_schur(
+        case, guard, monkeypatch):
+    import megba_tpu.solver.pcg as pcg_mod
+
+    region, tol, refuse, warm = _SCHUR_CASES[case]
+    system, r, Jc, Jp, cam_idx, pt_idx = build_test_system(
+        seed=4 if case == "refused" else 0)
+    region = jnp.asarray(region)
+    x0 = None
+    if warm:
+        x0 = 0.5 * schur_pcg_solve(
+            system, Jc, Jp, cam_idx, pt_idx, region, max_iter=300,
+            tol=1e-20, refuse_ratio=1e30).dx_cam
+
+    def solve():
+        # A fresh closure per call: jit traces it anew, after the patch.
+        return jax.jit(lambda system, region, x0: schur_pcg_solve(
+            system, Jc, Jp, cam_idx, pt_idx, region, max_iter=300, tol=tol,
+            refuse_ratio=refuse, x0=x0, guard=guard, max_restarts=2))(
+                system, region, x0)
+
+    new = solve()
+    monkeypatch.setattr(pcg_mod, "_pcg_core", _pcg_k_plus_1)
+    ref = solve()
+    for name in ("iterations", "r0_ratio"):
+        assert np.array_equal(np.asarray(getattr(new, name)),
+                              np.asarray(getattr(ref, name))), name
+    for name in ("dx_cam", "dx_pt", "rho"):
+        got, want = np.asarray(getattr(new, name)), np.asarray(
+            getattr(ref, name))
+        if warm:
+            np.testing.assert_allclose(got, want, rtol=0,
+                                       atol=1e-11 * np.max(np.abs(want)))
+        else:
+            assert np.array_equal(got, want), name
+    assert 1 < int(new.iterations) < 300
+    if case == "refused":
+        assert abs(float(new.rho)) >= tol
+
+
+@pytest.mark.parametrize("guard", [False, True])
+@pytest.mark.parametrize("case", ["cold", "converged_at_start", "warm"])
+def test_fused_pcg_runs_one_matvec_per_iteration(case, guard):
+    from megba_tpu.solver.pcg import _pcg_core
+
+    mat, b = _dense_spd(seed=5, n=16)
+    matvec, calls = _counting(lambda v: mat @ v)
+    # rho0 = <b, b> is far under the absurd tol: the loop never runs.
+    tol = 1e6 if case == "converged_at_start" else 1e-20
+    x0 = 0.5 * jnp.linalg.solve(mat, b) if case == "warm" else None
+    _, k, _, _, _, _ = _pcg_core(matvec, lambda r: r, b, 60, tol, 1e30,
+                                 False, x0=x0, guard=guard, max_restarts=2)
+    jax.effects_barrier()
+    k = int(k)
+    if case == "converged_at_start":
+        assert k == 0
+    else:
+        assert k > 0
+    # k CG iterations run k products; the warm start's r0 = b - A x0 is
+    # the one product outside the loop.
+    assert calls[0] == k + (1 if case == "warm" else 0)
