@@ -28,8 +28,7 @@ def ensure_host_device_floor(flags: str, floor: int) -> str:
     to at least `floor`: appended when absent, rewritten when a pre-set
     value is lower (e.g. the 2 this module exported before ba_2d_w4_f32
     existed, persisted in a dev shell or CI env), left alone when
-    already sufficient.  Shared with bench.py's MEGBA_BENCH_MESH2D
-    knob, which needs the same raise-to-floor before backend init."""
+    already sufficient."""
     m = _DEVICE_COUNT_RE.search(flags)
     if m is None:
         return (flags +

@@ -57,10 +57,8 @@ def pcg_body_collective_summary(
     """PCG-body collectives of a compiled program: (ops, kind -> count
     census, ring-model bytes moved per device per CG step).
 
-    The single body-mark filter + byte model behind both the
-    ProgramAudit.pcg_body_* passes and bench.py's mesh2d head-to-head,
-    so the bench census can never diverge from what the budget gate's
-    `collective_bytes_per_sp` axis pins."""
+    The body-mark filter + byte model behind the ProgramAudit.pcg_body_*
+    passes and the budget gate's `collective_bytes_per_sp` axis."""
     body = [op for op in hlo.collective_ops(compiled_ops)
             if op.op_name and PCG_BODY_MARK in op.op_name]
     census: Dict[str, int] = {}
@@ -531,8 +529,8 @@ class ProgramAudit:
                 + self.donation_violations())
 
     def summary(self) -> Dict[str, object]:
-        """JSON-able per-program audit summary (for bench.py and
-        SolveReport embedding)."""
+        """JSON-able per-program audit summary (the audit CLI's
+        `--summary` lines)."""
         pcg = self.pcg_body_collectives()
         return {
             "program": self.spec.name,

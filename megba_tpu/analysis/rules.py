@@ -7,7 +7,7 @@ Rule ids are kebab-case; suppress one finding with an inline
 |---|---|
 | host-callback | `jax.debug.callback` / `jax.debug.print` / `io_callback` / `pure_callback` only inside the designated host-interop modules (observability/, utils/debug.py) — anywhere else a callback silently punches a host round-trip into the fused device program |
 | np-in-jit | no `np.*` calls, `float(...)` or `.item()` coercions in functions reachable from a jitted entry point — each is either a trace-time constant bake (silent retrace per value) or a ConcretizationError waiting for the first non-static input |
-| implicit-dtype | `jnp.zeros/ones/empty/full/arange/eye/linspace/identity` must state a dtype (keyword or the documented positional slot); `jnp.array`/`jnp.asarray` of pure Python literals too — the f32 default silently breaks the f64/f32 parity evidence (DOUBLE_PARITY.json) |
+| implicit-dtype | `jnp.zeros/ones/empty/full/arange/eye/linspace/identity` must state a dtype (keyword or the documented positional slot); `jnp.array`/`jnp.asarray` of pure Python literals too — the f32 default silently breaks f64/f32 parity |
 | scalar-promotion | no strongly-typed scalar constructors (`np.float64(x)`, `jnp.int32(k)`, ...) as operands of array arithmetic in jit-reachable code — unlike weak Python scalars they promote the whole expression's dtype |
 | donated-reuse | an argument passed at a `donate_argnums` position of a locally-built `jax.jit` program must not be read after the call — the buffer is deleted by the call |
 | weak-literal | no BARE float literal as a `jnp.where` branch or `jnp.clip` bound in jit-reachable code — probed on this jaxlib: under x64 those positions materialise a `tensor<f64>` constant (plus a convert) in f32 programs, the dtype-census leak hand-fixed in PRs 3 and 6 (`jnp.where(safe, θ², 1.0)`, `jnp.where(..., 0.0, ...)`); use `zeros_like`/`ones_like`/`jnp.asarray(c, x.dtype)`.  Plain arithmetic (`2.0 * x`) and `jnp.maximum/minimum` literals promote weakly and are clean — the rule matches only the probed leaky positions |

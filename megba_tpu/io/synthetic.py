@@ -173,8 +173,8 @@ def make_synthetic_bal(
 
     `obs_per_point` may be fractional: a `frac(obs_per_point)` share of
     points gets `ceil` observations, the rest `floor`, so the total edge
-    count tracks `num_points * obs_per_point` — this is how the bench
-    matches the real BAL datasets' observation counts while keeping the
+    count tracks `num_points * obs_per_point` — this is how a scene
+    matches a real BAL dataset's observation count while keeping the
     point count exact.
 
     Degeneracy injection (pre-flight triage test fixtures — each knob
@@ -414,7 +414,7 @@ def make_fleet(
 ) -> List[SyntheticBAL]:
     """Generate a heterogeneous fleet of small BA problems, reproducibly.
 
-    The one generator the serving tests AND the fleet bench draw from,
+    The one generator the serving tests and smokes draw from,
     so "16 synthetic problems" means the same 16 scenes everywhere.
     `size_range` bounds the per-problem POINT count (inclusive); the
     camera count scales with it (~1 camera per 8 points, >= 3) and

@@ -92,13 +92,6 @@ class SolveReport:
     result: Dict[str, Any]  # final scalars (costs, iterations, ...)
     trace: Optional[Dict[str, list]] = None  # trace.trace_to_dict output
     memory: Optional[Dict[str, Any]] = None  # utils.meminfo.device_memory_stats
-    # Optional compiled-program audit context (analysis/program_audit):
-    # a producer-defined JSONable dict so a report line carries the
-    # static story next to the runtime one.  bench.py's
-    # MEGBA_BENCH_AUDIT=1 lane embeds {"backend", "x64", "gate",
-    # "programs": {name: ProgramAudit.summary(), ...}} (or {"backend",
-    # "error"} when the audit itself failed).
-    program_audit: Optional[Dict[str, Any]] = None
     # Optional serving-layer context (serving/batcher.py): bucket/lane
     # placement, batch latency and a FleetStats snapshot for reports
     # emitted by `solve_many` / `FleetQueue` — the fields the
@@ -191,7 +184,6 @@ def _decode_fallback_totals(trace, iterations: int) -> Optional[Dict[str, Any]]:
 
 def build_report(option, result, phases: Dict[str, Any],
                  problem: Dict[str, Any],
-                 audit: Optional[Dict[str, Any]] = None,
                  fleet: Optional[Dict[str, Any]] = None,
                  elastic: Optional[Dict[str, Any]] = None,
                  health: Optional[Dict[str, Any]] = None,
@@ -201,9 +193,7 @@ def build_report(option, result, phases: Dict[str, Any],
     `result` is an LMResult (trace included when the solve populated
     one); this call materializes the trace and result scalars, so the
     caller must be prepared for the implied device sync — telemetry-off
-    paths never get here.  `audit` optionally attaches a compiled-
-    program audit summary (analysis/program_audit) for the dispatched
-    configuration.
+    paths never get here.
     """
     from megba_tpu.observability.trace import trace_to_dict
     from megba_tpu.utils.meminfo import device_memory_stats
@@ -250,7 +240,6 @@ def build_report(option, result, phases: Dict[str, Any],
         result=result_block,
         trace=None if trace is None else trace_to_dict(trace, iterations),
         memory=device_memory_stats(),
-        program_audit=audit,
         fleet=fleet,
         elastic=elastic,
         health=health,

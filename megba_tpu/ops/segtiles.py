@@ -1074,13 +1074,13 @@ def squeeze_plans(plans: DualPlans) -> DualPlans:
 # ---------------------------------------------------------------------------
 #
 # Plan construction is pure host work (argsorts + bincounts over the
-# edge axis; ~270 ms per venice-scale solve, PROFILE.md) and depends
-# only on the problem GRAPH and the tile geometry — not on parameters
-# or observations.  Repeated solves of one problem (bench reruns,
-# chunked/checkpointed drivers, the auditor's canonical lowerings,
-# parameter sweeps) therefore reuse one plan, keyed by a content
-# fingerprint of the index arrays.  A strong digest (blake2b), not
-# Python's hash(): a collision would silently solve the wrong graph.
+# edge axis) and depends only on the problem GRAPH and the tile
+# geometry — not on parameters or observations.  Repeated solves of
+# one problem (benchmark windows, chunked/checkpointed drivers, the
+# auditor's canonical lowerings, parameter sweeps) therefore reuse one
+# plan, keyed by a content fingerprint of the index arrays.  A strong
+# digest (blake2b), not Python's hash(): a collision would silently
+# solve the wrong graph.
 
 _PLAN_CACHE: "dict" = {}
 _PLAN_CACHE_DEFAULT_MAX = 8  # LRU bound: plans pin host+device index arrays
@@ -1511,7 +1511,7 @@ def cached_cluster_plan(
     blake2b content fingerprint of the index arrays + mask + EVERY
     aggregation parameter (target, world_size, smoothing omega),
     exactly like the tile plans, so repeated solves of one problem
-    (bench reruns, chunked drivers, the auditor's canonical lowerings)
+    (benchmark windows, chunked drivers, the auditor's canonical lowerings)
     build the cluster graph once.  `smooth_omega` does not change the
     plan CONTENT today (smoothing is a device-side build step over the
     planned indices), but it is part of the key by contract: a
@@ -1788,7 +1788,7 @@ class CameraTilePlan:
     bucket_slot: np.ndarray  # [E*C*C, Lb] int32 device-local edge slot
     bucket_ptl: np.ndarray  # [E*C*C, Lb] int32 shard-LOCAL point
     bucket_mask: np.ndarray  # [E*C*C, Lb] int32 1=real pair
-    # Streaming-reuse statistics of the final stream (bench evidence).
+    # Streaming-reuse statistics of the final stream.
     reuse: dict
 
 

@@ -1035,9 +1035,8 @@ class FleetRouter:
         # instead of all workers thrashing one shared set (the
         # data-parallel deployment shape, one host's cores = one
         # worker's world).  True = cores // n each; an int = exactly
-        # that many cores per worker (the bench's equal-resource
-        # scaling sweeps pin fed_1 and fed_n to the SAME per-worker
-        # slice so the 1→N curve compares like with like).
+        # that many cores per worker (so a 1-worker and an n-worker
+        # router can be given the same per-worker slice).
         slices: List[Optional[List[int]]] = [None] * n
         if pin_cpus and n:
             try:

@@ -47,15 +47,10 @@ def default_use_tiled(dtype) -> bool:
 
     Float32 on TPU backends only: the tiled XLA fallback on CPU is
     slower and fatter than the chunked scatter-add build, and float64
-    never rides the kernels.  MEGBA_TILED=1/0 force-enables/disables.
-    One definition shared by flat_solve and bench.py so the bench can
-    never measure a different engine than production selects.
+    never rides the kernels.
     """
     if np.dtype(dtype) != np.float32:
         return False
-    env = os.environ.get("MEGBA_TILED")
-    if env is not None:
-        return env != "0"
     return jax.default_backend() == "tpu"
 
 
@@ -164,7 +159,6 @@ def flat_solve(
     every per-edge scatter/gather with block-aligned MXU reductions),
     OFF otherwise (float64 verification and CPU runs keep the chunked
     scatter-add build, whose transient memory is bounded).
-    MEGBA_TILED=1/0 force-enables/disables.
 
     `fault_plan` (robustness.faults.FaultPlan, edge_nan in the CALLER's
     edge order) seeds a deterministic fault into the solve; its edge

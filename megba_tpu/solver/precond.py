@@ -1,12 +1,8 @@
-"""Pluggable Schur-complement preconditioners (the 29.6-iters/LM lever).
+"""Pluggable Schur-complement preconditioners.
 
-Bench history (BENCH_r02-r05) pinned the tol-mode inner solve at ~29.6
-PCG iterations per LM step across four rounds: after the fused
-Chronopoulos-Gear body and Eisenstat-Walker forcing (PR 4) removed the
-outer-loop waste, the BLOCK-JACOBI preconditioner — not the matvec — is
-the measured ceiling.  This module makes the preconditioner a pluggable
-operator family (`SolverOption.precond`, common.PrecondKind) with three
-matrix-free members that all run inside the single fused PCG program:
+The preconditioner is a pluggable operator family
+(`SolverOption.precond`, common.PrecondKind) whose matrix-free members
+all run inside the single fused PCG program:
 
 JACOBI — the extracted baseline: apply the inverted block diagonal
   (damped Hpp, or the true Schur diagonal under
@@ -59,8 +55,9 @@ TWO_LEVEL — a BA-shaped two-level (multigrid-flavoured) scheme:
   `ba_twolevel_w2_f32` canonical program pins exactly 2 all-reduces
   per S·p there).  Unlike the ADDITIVE combination D⁻¹ + RᵀA_c⁻¹R
   (which re-widens the spectrum wherever coarse and fine ranges
-  overlap — measured 1.5x MORE iterations on the venice bench), the
-  multiplicative cycle leaves coarse modes with eigenvalue exactly 1.
+  overlap — measured 1.5x MORE iterations on a venice-shaped CPU
+  scene), the multiplicative cycle leaves coarse modes with eigenvalue
+  exactly 1.
   M⁻¹ is SPD: both terms are PSD and their kernels are disjoint
   (P r = r on ker(R), where D⁻¹ is PD).
 
@@ -94,16 +91,15 @@ silent: `PCGResult.precond_fallback` carries an enum-coded int32
 (low 16 bits block count, high bits a per-level bit-field —
 encode/decode below) into `SolveTrace`/`SolveReport`.
 
-Measured (venice-10% synthetic bench, CPU lane, inexact-LM config):
-NEUMANN k=1 cuts total PCG iterations 40% (70 -> 42) at 9e-8 relative
-cost gap — the run_tests.sh smoke gates on >= 30%.  TWO_LEVEL is
-dense-verified exact and cuts the preconditioned condition number
-54 -> 4.3 on small systems, but the bench SYNTHETIC's camera graph is
-an expander ((base + j*stride) mod Nc observation assignment — no
-cluster structure), so its coarse space captures nothing there and
-block-Jacobi stays the better default on that lane; it targets
-spatially-local real scenes.  See ARCHITECTURE.md "Preconditioner
-hierarchy".
+Measured on the CPU (venice-10% synthetic scene, inexact-LM config):
+NEUMANN k=1 cut total PCG iterations 40% (70 -> 42) at 9e-8 relative
+cost gap.  TWO_LEVEL is dense-verified exact and cuts the
+preconditioned condition number 54 -> 4.3 on small systems, but the
+synthetic scene's camera graph is an expander ((base + j*stride) mod
+Nc observation assignment — no cluster structure), so its coarse space
+captures nothing there and block-Jacobi stays the better default on
+it; it targets spatially-local real scenes.  See ARCHITECTURE.md
+"Preconditioner hierarchy".
 """
 
 from __future__ import annotations
@@ -137,7 +133,7 @@ _PAIR_CHUNK = 65_536
 # survive into A_c, and INVERTING them amplifies directions the Krylov
 # iteration never needed to resolve — measured: unfiltered coarse
 # solves cost 66-78 PCG iters/LM vs block-Jacobi's flat ~43 at region
-# 1e5-3e5 on the venice-3% bench, flipping the two-level win into a
+# 1e5-3e5 on a venice-3% CPU scene, flipping the two-level win into a
 # loss.  Filtered, those modes fall through to the smoother, which
 # treats them exactly as block-Jacobi always has.
 _COARSE_EIG_FLOOR = 1e-5

@@ -1,68 +1,10 @@
-"""XLA memory analysis of the production single-chip LM program.
-
-Shared by the evidence scripts (scripts/hbm_budget.py,
-scripts/jacobian_mode_bench.py): one definition of "lower + compile the
-single-solve program for this synthetic problem and read
-compiled.memory_analysis()" so the two artifacts can never measure
-subtly different programs.
-"""
+"""Live device allocator statistics for telemetry reports."""
 
 from __future__ import annotations
 
 from typing import Optional
 
 import numpy as np
-
-
-def single_solve_memory_analysis(s, option, residual_jac_fn,
-                                 keys: tuple = ()) -> dict:
-    """memory_analysis() of the jitted single-device solve, as a dict.
-
-    `s` is a synthetic problem (io/synthetic.make_synthetic_bal result);
-    edges are camera-sorted + quantum-padded exactly as flat_solve's
-    non-tiled path does, and the program is the production
-    _build_single_solve one.  The returned dict ALWAYS carries
-    `n_edges_padded` (callers size analytic models from it); the
-    XLA byte fields are present only when the backend exposes a
-    memory analysis.
-    """
-    import jax.numpy as jnp
-
-    from megba_tpu.core.types import pad_edges
-    from megba_tpu.native import sort_edges_by_camera
-    from megba_tpu.observability.emit import next_verbose_token
-    from megba_tpu.solve import EDGE_QUANTUM, _build_single_solve
-
-    dtype = np.dtype(option.dtype)
-    n_cam = s.cameras0.shape[0]
-    perm = sort_edges_by_camera(s.cam_idx, n_cam)
-    obs, ci, pi = s.obs[perm], s.cam_idx[perm], s.pt_idx[perm]
-    obs, ci, pi, mask = pad_edges(obs, ci, pi, EDGE_QUANTUM, dtype=dtype)
-
-    jitted = _build_single_solve(residual_jac_fn, option, keys, False, True)
-    args = (
-        jnp.asarray(np.ascontiguousarray(s.cameras0.T)),
-        jnp.asarray(np.ascontiguousarray(s.points0.T)),
-        jnp.asarray(np.ascontiguousarray(obs.T)),
-        jnp.asarray(ci), jnp.asarray(pi), jnp.asarray(mask),
-        jnp.asarray(1e3, dtype), jnp.asarray(2.0, dtype),
-        jnp.asarray(next_verbose_token(), jnp.int32), None)
-    ma = jitted.lower(*args).compile().memory_analysis()
-    out: dict = {"n_edges_padded": int(obs.shape[0])}
-    if ma is None:
-        return out
-    for k in ("argument_size_in_bytes", "output_size_in_bytes",
-              "temp_size_in_bytes", "alias_size_in_bytes",
-              "generated_code_size_in_bytes"):
-        v = getattr(ma, k, None)
-        if v is not None:
-            out[k] = int(v)
-    out["peak_estimate_bytes"] = (
-        out.get("argument_size_in_bytes", 0)
-        + out.get("output_size_in_bytes", 0)
-        + out.get("temp_size_in_bytes", 0)
-        - out.get("alias_size_in_bytes", 0))
-    return out
 
 
 def device_memory_stats(device=None) -> Optional[dict]:
@@ -72,8 +14,8 @@ def device_memory_stats(device=None) -> Optional[dict]:
     peak_bytes_in_use, bytes_limit, ...); XLA:CPU does not — telemetry
     (observability/report.py) records whatever the backend offers and
     omits the section otherwise, so reports stay backend-portable.
-    Unlike `single_solve_memory_analysis` this costs no compilation: it
-    reads counters, so it is cheap enough for the per-solve report path.
+    It reads counters, so it is cheap enough for the per-solve report
+    path.
     """
     import jax
 

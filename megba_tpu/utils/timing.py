@@ -120,7 +120,7 @@ class PhaseTimer:
         """Count an instantaneous event (zero duration) — e.g. the host
         plan cache's `plan_cache_hit` counter.  Shows up in `as_dict()`
         / `report()` with total_s 0.0 and `calls` = occurrence count, so
-        the SolveReport/bench phase schema is unchanged."""
+        the SolveReport phase schema is unchanged."""
         self.totals.setdefault(name, 0.0)
         self.counts[name] = self.counts.get(name, 0) + n
 

@@ -1,5 +1,31 @@
 #!/bin/bash
-# Full test suite in two processes.
+# Full CPU test suite: static checks, every pytest test (slow ones
+# included) in two processes, then end-to-end smokes.  Lanes, in order:
+#
+#   lint.sh              linter, strict-dtype sanitizer, compiled-program
+#                        audit against ANALYSIS_BUDGET.json, concurrency
+#                        and identity rules
+#   pytest (two halves)  tests/test_[a-o]*.py, then tests/test_[p-z]*.py
+#   observability        a telemetry-on solve writes a SolveReport the
+#                        summarize CLI renders
+#   fault injection      venice-10% NaN burst at a chunk resume: guards
+#                        recover within rtol 1e-5 (worlds 1 and 2);
+#                        unguarded the cost goes non-finite
+#   serving chaos        poisoned and doomed problems in a FleetQueue:
+#                        escalation heals, shedding, breaker, clean
+#                        problems bitwise vs solve_many
+#   triage               REJECT fails fast with no dispatch, REPAIR lands
+#                        on the clean run, fleet batch-mates bitwise
+#   mixed-factor fleet   four residual families in one queue, one compile
+#                        per (factor, bucket), results bitwise
+#   federation           artifact warm start with zero traces, worker
+#                        SIGKILL reroute, results bitwise vs one host
+#   elastic chaos        2-process gloo solve, rank 1 killed, rank 0
+#                        resumes at world 1 (skipped without gloo)
+#   network chaos        TCP fleet through a chaos proxy: partition,
+#                        reconnect, deduplicated resend, worker kill
+#
+# The benchmark is benchmark/run.py on the chip (PERF.md), not here.
 #
 # A single pytest process accumulates hundreds of XLA:CPU JIT
 # compilations over the full suite; on this sandbox's jaxlib that
@@ -46,221 +72,6 @@ assert res.trace is not None and int(res.iterations) > 0
 PY
 JAX_PLATFORMS=cpu python -m megba_tpu.observability.summarize "$SMOKE" | grep -q "phases:"
 echo "observability smoke OK"
-
-# The bench head-to-heads' CPU gates.  bench.py itself runs on a TPU
-# only; scripts/bench_gates.py builds bench's scene on the CPU at the
-# smoke scale and calls the same head-to-head functions, printing one
-# {"gates": {...}} line (no bench metric) for the asserts below.
-#
-# Inexact-LM smoke: venice-10% scene with the
-# MEGBA_BENCH_FORCING=1 head-to-head — adaptive forcing + warm starts
-# must cut total PCG iterations >= 30% at an unchanged final cost
-# (the curve-parity gap_tol regime, utils/curves), and the comparison
-# rides the bench JSON line.  MEGBA_BENCH_FLEET=16 rides the SAME bench
-# run: 16 heterogeneous synthetic problems (io/synthetic.make_fleet)
-# solved as a serial flat_solve loop vs one batched solve_many pass
-# (serving layer) — steady-state batched problems/sec must strictly
-# beat the serial loop and every lane must report a terminal
-# SolveStatus.  MEGBA_BENCH_BF16=1 rides the same run too: the bf16
-# MXU pipeline head-to-head (cost band + guard cleanliness + halved
-# bytes axes; asserted below, certified in BENCH_bf16.json).
-# MEGBA_BENCH_OBS=1 rides the same run as well: the observability-plane
-# overhead head-to-head (ISSUE 16) — solve_many with the plane off vs
-# metrics+spans on, interleaved best-of-6 pairs, <= 2% overhead band
-# (asserted below, certified in BENCH_obs.json).
-FORCING_OUT=$(mktemp /tmp/megba_forcing_smoke.XXXXXX.json)
-trap 'rm -f "$SMOKE" "$FORCING_OUT"' EXIT
-JAX_PLATFORMS=cpu MEGBA_BENCH_CONFIG=venice MEGBA_BENCH_SCALE=0.1 \
-MEGBA_BENCH_CONVERGENCE=0 MEGBA_BENCH_FORCING=1 MEGBA_BENCH_FLEET=16 \
-MEGBA_BENCH_PRECOND=neumann MEGBA_BENCH_NEUMANN_ORDER=1 \
-MEGBA_BENCH_BF16=1 MEGBA_BENCH_OBS=1 \
-  python scripts/bench_gates.py > "$FORCING_OUT"
-python - "$FORCING_OUT" <<'PY'
-import json
-import sys
-
-line = [l for l in open(sys.argv[1]) if l.strip().startswith("{")][-1]
-fc = json.loads(line)["gates"]["forcing"]
-print("inexact-LM smoke:", json.dumps(fc))
-assert fc["pcg_reduction"] >= 0.30, (
-    f"forcing cut only {100 * fc['pcg_reduction']:.1f}% of PCG iterations "
-    "(need >= 30%)")
-assert fc["cost_rel_gap"] <= 1e-2, (
-    f"forcing moved the final cost by {fc['cost_rel_gap']:.2e} "
-    "(> 1e-2 curve gap_tol)")
-
-# Preconditioner smoke (ISSUE 7): under the SAME inexact-LM production
-# config, the Neumann operator family must cut total PCG iterations
-# >= 30% vs block-Jacobi at <= 1e-2 relative final-cost gap.  (The
-# two-level operator is pinned structurally by the ba_twolevel_w2_f32
-# audit program and tests/test_precond.py; on THIS bench's synthetic
-# expander-like camera graph it has no cluster structure to exploit, so
-# the iteration gate rides the operator that wins here — see
-# ARCHITECTURE.md "Preconditioner hierarchy".)
-pc = json.loads(line)["gates"]["precond"]
-print("precond smoke:", json.dumps(pc))
-assert pc["kind"] == "neumann", pc
-assert pc["pcg_reduction"] >= 0.30, (
-    f"{pc['kind']} cut only {100 * pc['pcg_reduction']:.1f}% of PCG "
-    "iterations vs block-Jacobi (need >= 30%)")
-assert pc["cost_rel_gap"] <= 1e-2, (
-    f"{pc['kind']} moved the final cost by {pc['cost_rel_gap']:.2e} "
-    "(> 1e-2 curve gap_tol)")
-
-fl = json.loads(line)["gates"]["fleet"]
-print("fleet smoke:", json.dumps(fl))
-TERMINAL = {"converged", "max_iter", "stalled", "recovered",
-            "fatal_nonfinite"}
-assert fl["problems"] >= 16, fl
-assert set(fl["statuses"]) <= TERMINAL and fl["statuses"], (
-    f"non-terminal per-lane status in {fl['statuses']}")
-# Sanity band, not a parity proof: this lane runs f32/x64-off, where
-# camera/point bucket padding reorders compensated sums and the
-# un-converged trajectories drift ~1e-2 relative.  The strict contract
-# (bitwise padding, rtol 1e-6 vs flat_solve) is pinned under x64 by
-# tests/test_serving.py.
-assert fl["max_cost_rel_gap"] <= 5e-2, (
-    f"batched final costs drifted {fl['max_cost_rel_gap']:.2e} from the "
-    "serial loop (> 5e-2 f32 sanity band)")
-assert fl["problems_per_sec_batched"] > fl["problems_per_sec_serial"], (
-    f"batched {fl['problems_per_sec_batched']} problems/s did not beat "
-    f"the serial loop at {fl['problems_per_sec_serial']} problems/s")
-
-# bf16 MXU pipeline smoke (ISSUE 15): the SAME venice-10% run solved
-# f32 vs bf16 under the inexact-LM config with PR 5's guards ARMED —
-# the bf16 candidate must converge within the documented cost-gap band
-# with ZERO guard/recovery/breakdown events (a clean bf16 run must not
-# lean on the containment machinery), and the auditor's
-# collective_bytes_per_sp axis must come out at exactly HALF the f32
-# program's, live (re-audited in-process) and committed
-# (ANALYSIS_BUDGET.json).  Certified in BENCH_bf16.json.
-bf = json.loads(line)["gates"]["bf16"]
-print("bf16 smoke:", json.dumps({k: bf[k] for k in (
-    "cost_rel_gap", "cost_gap_band", "pcg_iters_delta",
-    "guard_events_bf16", "committed_bytes_per_sp")}))
-assert bf["cost_rel_gap"] <= bf["cost_gap_band"], (
-    f"bf16 final cost drifted {bf['cost_rel_gap']:.2e} from the f32 "
-    f"control (> {bf['cost_gap_band']:.0e} documented band)")
-assert bf["guard_events_bf16"] == 0, (
-    f"bf16 tripped {bf['guard_events_bf16']} guard/recovery event(s) "
-    "on a clean run")
-assert bf["bf16"]["status"] in TERMINAL and bf["bf16"]["recoveries"] == 0
-for cand, ctrl in (("ba_bf16_w2_f32", "ba_sharded_w2_f32"),
-                   ("ba_bf16_2d_w4_f32", "ba_2d_w4_f32")):
-    c = bf["committed_bytes_per_sp"]
-    assert c[cand] == 0.5 * c[ctrl], (
-        f"{cand} bytes/sp {c[cand]} is not half of {ctrl}'s {c[ctrl]}")
-live = bf["audited_live"]
-if live:
-    assert live["ba_bf16_w2_f32"]["collective_bytes_per_sp"] == \
-        0.5 * live["ba_sharded_w2_f32"]["collective_bytes_per_sp"], live
-    assert not any(v["violations"] for v in live.values()), live
-
-# Observability-plane overhead smoke (ISSUE 16): the SAME venice-10%
-# run re-solves the fleet with metrics+spans armed vs the plane off
-# (interleaved best-of-6 pairs so container drift cancels).  The plane
-# is host-side only — the jitted programs are byte-identical (pinned by
-# the audit gate) — so the overhead must sit inside the 2% band, and
-# the instrumented side must actually have instrumented (non-empty
-# metric families + spans).  Certified in BENCH_obs.json.
-ob = json.loads(line)["gates"]["obs"]
-print("obs overhead smoke:", json.dumps(ob))
-assert ob["within_band"] and ob["overhead_pct"] <= ob["band_pct"], (
-    f"observability plane cost {ob['overhead_pct']:.2f}% on the fleet "
-    f"pass (> {ob['band_pct']:.0f}% band)")
-assert ob["metric_families"] > 0 and ob["spans"] > 0, (
-    f"instrumented side recorded nothing: {ob}")
-PY
-echo "inexact-LM + fleet + bf16 + obs smoke OK"
-
-# Fused edge-pipeline smoke (ISSUE 19): the venice scene solved through
-# the fused Pallas kernels (gather -> contract -> scatter in one kernel
-# per direction + fused M^-1 apply) vs the tiled XLA lowering on the
-# SAME edge plans, guards armed both sides.  The acceptance pin:
-# end-to-end LM cost within 1e-5 with ZERO guard/recovery events, and
-# the analytical edge-budget axes must show the fusion actually deletes
-# transient HBM round-trips.  Off-TPU the kernels run under Pallas
-# INTERPRET mode — the parity certificate, but per-grid-step host
-# execution makes venice-10% (~500k edges) wall-clock-prohibitive on
-# CPU runners — so the CPU gate runs the identical contract at
-# venice-1% (~50k edges: same multi-bucket multi-tile plan structure,
-# ~100 tiles per direction); on the chip the kernels compile through
-# Mosaic and `python chip_smoke.py` checks the fused path at full venice
-# size.  Certified in BENCH_fused.json (lane-tagged).
-FUSED_OUT=$(mktemp /tmp/megba_fused_smoke.XXXXXX.json)
-trap 'rm -f "$SMOKE" "$FORCING_OUT" "$FUSED_OUT"' EXIT
-JAX_PLATFORMS=cpu MEGBA_BENCH_CONFIG=venice MEGBA_BENCH_SCALE=0.01 \
-MEGBA_BENCH_CONVERGENCE=0 MEGBA_BENCH_FUSED=1 \
-  python scripts/bench_gates.py > "$FUSED_OUT"
-python - "$FUSED_OUT" <<'PY'
-import json
-import sys
-
-line = [l for l in open(sys.argv[1]) if l.strip().startswith("{")][-1]
-fu = json.loads(line)["gates"]["fused"]
-print("fused smoke:", json.dumps({k: fu[k] for k in (
-    "cost_rel_gap", "cost_gap_band", "guard_events_fused",
-    "transient_bytes_deleted_per_sp", "scene")}))
-TERMINAL = {"converged", "max_iter", "stalled", "recovered",
-            "fatal_nonfinite"}
-assert fu["cost_rel_gap"] <= fu["cost_gap_band"], (
-    f"fused final cost drifted {fu['cost_rel_gap']:.2e} from the tiled "
-    f"XLA lowering (> {fu['cost_gap_band']:.0e} acceptance band)")
-assert fu["guard_events_fused"] == 0, (
-    f"fused run tripped {fu['guard_events_fused']} guard/recovery "
-    "event(s) on a clean run")
-assert fu["fused_pallas"]["status"] in TERMINAL, fu["fused_pallas"]
-assert fu["tiles"] and fu["tiles"]["plan"] == "tiled_1d", (
-    f"fused solve did not report tile metrics: {fu['tiles']}")
-assert fu["tiles"]["fused_to_pt"]["tiles"] > 1, (
-    f"fused smoke degenerated to a single tile: {fu['tiles']}")
-assert fu["transient_bytes_deleted_per_sp"] > 0, (
-    "edge-budget pricing shows no transient traffic deleted — the "
-    f"fused arm is not cheaper: {fu}")
-PY
-echo "fused edge-pipeline smoke OK"
-
-# Locality-scene multilevel smoke (ISSUE 11): the venice-10% scene on
-# a RING-locality scene (banded camera co-observation — the structure
-# real BAL graphs have; MEGBA_BENCH_LOCALITY=ring) with the MULTILEVEL
-# camera-graph hierarchy as candidate.  Unlike the expander scene
-# (where the coarse space is structurally inert — PR 7's honest
-# negative result, and why the Neumann smoke above stays as-is), the
-# locality scene has the cluster-constant slow modes the coarse space
-# exists to remove: the hierarchy must cut total PCG iterations >= 30%
-# vs block-Jacobi at <= 1e-2 relative final-cost gap, and the JSON
-# line must carry the hierarchy depth + per-level fallback decode.
-LOCALITY_OUT=$(mktemp /tmp/megba_locality_smoke.XXXXXX.json)
-trap 'rm -f "$SMOKE" "$FORCING_OUT" "$LOCALITY_OUT"' EXIT
-JAX_PLATFORMS=cpu MEGBA_BENCH_CONFIG=venice MEGBA_BENCH_SCALE=0.1 \
-MEGBA_BENCH_CONVERGENCE=0 MEGBA_BENCH_LOCALITY=ring \
-MEGBA_BENCH_PRECOND=multilevel \
-  python scripts/bench_gates.py > "$LOCALITY_OUT"
-python - "$LOCALITY_OUT" <<'PY'
-import json
-import sys
-
-line = [l for l in open(sys.argv[1]) if l.strip().startswith("{")][-1]
-d = json.loads(line)
-assert d["gates"]["locality"] == "ring", d["gates"].get("locality")
-pc = d["gates"]["precond"]
-print("locality multilevel smoke:", json.dumps(pc))
-assert pc["kind"] == "multilevel", pc
-assert pc["locality"] == "ring", pc
-# The hierarchy actually went past two levels on this scene.
-assert pc["hierarchy_levels"] >= 3, pc
-assert pc["pcg_reduction"] >= 0.30, (
-    f"multilevel cut only {100 * pc['pcg_reduction']:.1f}% of PCG "
-    "iterations vs block-Jacobi on the locality scene (need >= 30%)")
-assert pc["cost_rel_gap"] <= 1e-2, (
-    f"multilevel moved the final cost by {pc['cost_rel_gap']:.2e} "
-    "(> 1e-2 curve gap_tol)")
-# Healthy hierarchy: the win must come from the full cycle, not a
-# degraded one (fallback rides the JSON line either way).
-fb = pc["fallback"] or {}
-assert not fb.get("coarse"), f"hierarchy degraded during the smoke: {fb}"
-PY
-echo "locality multilevel smoke OK"
 
 # Fault-injection smoke: venice-10% with a NaN burst seeded at GLOBAL
 # LM iteration 3 — i.e. at the checkpointed driver's chunk-resume
@@ -351,7 +162,7 @@ echo "fault-injection smoke OK"
 # survive all of it; and `summarize --aggregate` must render the
 # retry/shed/deadline-miss/breaker counters from the report stream.
 CHAOS_SINK=$(mktemp /tmp/megba_chaos_smoke.XXXXXX.jsonl)
-trap 'rm -f "$SMOKE" "$FORCING_OUT" "$FUSED_OUT" "$LOCALITY_OUT" "$CHAOS_SINK"' EXIT
+trap 'rm -f "$SMOKE" "$CHAOS_SINK"' EXIT
 JAX_PLATFORMS=cpu MEGBA_CHAOS_SINK="$CHAOS_SINK" python - <<'PY'
 import dataclasses
 import os
@@ -526,7 +337,7 @@ echo "serving chaos smoke OK"
 # the same composition.  `summarize --aggregate` renders the triage
 # counters from the report stream.
 TRIAGE_SINK=$(mktemp /tmp/megba_triage_smoke.XXXXXX.jsonl)
-trap 'rm -f "$SMOKE" "$FORCING_OUT" "$FUSED_OUT" "$LOCALITY_OUT" "$CHAOS_SINK" "$TRIAGE_SINK"' EXIT
+trap 'rm -f "$SMOKE" "$CHAOS_SINK" "$TRIAGE_SINK"' EXIT
 JAX_PLATFORMS=cpu python - <<'PY'
 import time
 

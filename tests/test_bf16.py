@@ -3,9 +3,10 @@ collective payload casts, escalation/serving composition, and the
 slow-lane solve parity + guard-cleanliness contracts.
 
 Tier-1 tests here are compile-free (option validation, policy casts on
-eager scalars, fingerprint splits, the escalation rung transform);
-everything that lowers or solves a program is slow-marked (tier-1
-budget — see ROADMAP).
+eager scalars, fingerprint splits, the escalation rung transform), plus
+one small single-device solve for the cost band and guard cleanliness;
+the other solves and lowerings are slow-marked (tier-1 budget — see
+ROADMAP).
 """
 
 import numpy as np
@@ -168,7 +169,7 @@ def test_escalation_rung2_strips_bf16():
 
 
 # ---------------------------------------------------------------------------
-# Slow lane: the pipeline actually solves, guard-clean, at parity
+# Solves: the pipeline actually solves, guard-clean, at parity
 # ---------------------------------------------------------------------------
 
 def _scene():
@@ -199,12 +200,10 @@ def _solve(s, **kw):
                       s.pt_idx, opt, use_tiled=False)
 
 
-@pytest.mark.slow
 def test_bf16_solve_parity_and_guard_clean():
     """The bf16 pipeline converges within the documented band of the
     f32 control with ZERO guard/recovery events — the acceptance
-    contract, on the small scene (the venice-10% certification lives
-    in run_tests.sh / BENCH_bf16.json)."""
+    contract, on the small scene."""
     s = _scene()
     r32 = _solve(s)
     rbf = _solve(s, bf16=True)
@@ -231,8 +230,8 @@ def test_bf16_composes_with_2d_mesh():
     # MID-trajectory costs wobble several % between summation
     # groupings (the 2-D bf16 operator regroups sums on top of the
     # rounding), while the converged basins agree at bf16-operator
-    # accuracy — measured 1.6e-3 here; venice-10% certifies 8.9e-8
-    # (BENCH_bf16.json).
+    # accuracy — measured 1.6e-3 here; 8.9e-8 on a venice-10% CPU
+    # scene.
     s = _scene()
     rbf = _solve(s, world=4, mesh2d=True, bf16=True, bf16_collectives=True,
                  lm=20)

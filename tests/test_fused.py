@@ -13,9 +13,10 @@ Three layers of coverage:
   The bf16 arm additionally asserts the f32-accumulator contract at
   the kernel's OUTPUT dtype (the in-kernel trace assert in
   `_contract_rows` fires under interpret mode too).
-- END-TO-END (slow): flat_solve fused-on vs fused-off LM cost parity
-  at the pinned tolerance, including the newly-legal tiled+bf16 arm
-  and the 2-D mesh composition.
+- END-TO-END: flat_solve fused-on vs fused-off LM cost parity at the
+  pinned tolerance (tier-1 on the default implicit config; slow for
+  the explicit arm, the newly-legal tiled+bf16 arm and the 2-D mesh
+  composition).
 """
 
 import dataclasses
@@ -346,7 +347,6 @@ def _rel_gap(a, b):
     return abs(float(a) - float(b)) / max(1.0, abs(float(b)))
 
 
-@pytest.mark.slow
 def test_flat_solve_fused_cost_parity_tiled():
     s = _ba()
     base = _solve(s, _opt(), use_tiled=True)
@@ -363,8 +363,7 @@ def test_flat_solve_fused_cost_parity_explicit_compute():
     # ~1e-5 apart — pure ordering noise, not kernel error (single-kernel
     # parity is pinned at 1e-6 above; the strict <=1e-5 end-to-end pin
     # rides the default IMPLICIT config in
-    # test_flat_solve_fused_cost_parity_tiled and the run_tests.sh
-    # venice smoke).  Longer LM runs only widen the branch
+    # test_flat_solve_fused_cost_parity_tiled).  Longer LM runs only widen the branch
     # divergence, so the band here is 5e-5 at the short config.
     from megba_tpu.common import ComputeKind
 

@@ -5,9 +5,11 @@ test here draws from ONE canonical option per dtype (OPT64 / OPT32) and
 a small closed set of (bucket, lanes) shapes — the jit caches and the
 persistent compile cache make the marginal cost of each extra test a
 solve, not a compile.  Every test that traces/compiles a solver program
-is additionally marked `slow`: the tier-1 sweep (`pytest -m 'not
-slow'`) keeps only the host-side property/unit tests, and the full
-two-process lane (scripts/run_tests.sh, no filter) runs everything.
+is additionally marked `slow`, but one: the tier-1 sweep (`pytest -m
+'not slow'`) keeps the host-side property/unit tests plus
+`test_fleet_vs_natural_flat_solve_rtol` (batched lanes against
+flat_solve), and the full two-process lane (scripts/run_tests.sh, no
+filter) runs everything.
 """
 
 import dataclasses
@@ -270,7 +272,6 @@ def test_fleet_16_matches_flat_solve_one_compile_per_bucket():
     assert d["problems_per_sec"] > 0.0
 
 
-@pytest.mark.slow
 def test_fleet_vs_natural_flat_solve_rtol():
     """Cross-shape check: lanes also match flat_solve at the problem's
     NATURAL (unbucketed) shapes within the acceptance band."""

@@ -1,6 +1,5 @@
 """Linear system + Schur-PCG tests vs dense direct solve (SURVEY.md §4c)."""
 
-import os
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -223,35 +222,6 @@ def test_padding_edges_are_inert():
     np.testing.assert_allclose(padded.Hll, base.Hll, rtol=1e-12)
     np.testing.assert_allclose(padded.g_cam, base.g_cam, rtol=1e-12)
     np.testing.assert_allclose(padded.g_pt, base.g_pt, rtol=1e-12)
-
-
-@pytest.mark.slow
-def test_mixed_precision_validation_pipeline(tmp_path):
-    """End-to-end run of scripts/mixed_precision_validation.py at small
-    scale: bf16-coupling PCG must reach the f32 optimum (rel tol 1e-3)
-    and the script must exit 0 and write its artifact (VERDICT r04
-    item 5 — config 5 becomes a pure bench run when hardware answers)."""
-    import json
-    import subprocess
-    import sys
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["MEGBA_MP_CONFIG"] = "venice"
-    env["MEGBA_BENCH_SCALE"] = "0.02"
-    out_path = str(tmp_path / "mp.json")
-    env["MEGBA_MP_OUT"] = out_path  # keep the full-scale artifact intact
-    env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
-    env.pop("XLA_FLAGS", None)
-    p = subprocess.run(
-        [sys.executable, os.path.join(repo, "scripts",
-                                      "mixed_precision_validation.py")],
-        capture_output=True, text=True, env=env, cwd=repo, timeout=600)
-    assert p.returncode == 0, p.stdout + p.stderr
-    payload = json.loads(open(out_path).read())
-    assert payload["pass"] is True
-    assert "bf16_coupling" in payload["runs"] and "f32" in payload["runs"]
 
 
 # ---------------------------------------------- fused CG: k products for k
